@@ -58,21 +58,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import resource
 import sys
 import time
-
-# The dynamic epoch scan is a long chain of tiny fused loops; XLA's legacy
-# CPU runtime executes that shape 2-4x faster than the thunk runtime on the
-# smoke sizes (measured on the committed baseline's machine), so pin it for
-# benchmarking unless the caller already chose.  Must happen before jax
-# initializes its backends.
-if "xla_cpu_use_thunk_runtime" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_use_thunk_runtime=false"
-    ).strip()
 
 import jax
 import numpy as np
@@ -88,6 +77,7 @@ from repro.cluster import (
     sample_job_times,
     simulate_fifo,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.core import traces
 from repro.core.planner import RedundancyPlanner
 from repro.core.service_time import Empirical, Exponential, Pareto, ShiftedExponential
@@ -788,6 +778,7 @@ def main() -> None:
     )
     ap.add_argument("--out", type=pathlib.Path, default=ART / "cluster_bench.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = _cfg(args.smoke)
     t0 = time.time()

@@ -27,6 +27,7 @@ from repro.cluster import (
     Scenario,
     simulate_epochs,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.core.planner import RedundancyPlanner
 from repro.core.service_time import Pareto
 from repro.distributed import rdp
@@ -125,4 +126,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -5,6 +5,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import analysis, simulator
 from repro.core.planner import RedundancyPlanner, fit_service_time
 from repro.core.service_time import Exponential, Pareto, ShiftedExponential
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -3,6 +3,7 @@ replication from the measured service times (paper §VII methodology).
 
 Run:  PYTHONPATH=src python examples/serve_lm.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.launch import serve
 
 
@@ -15,4 +16,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ Run me::
     PYTHONPATH=src python examples/slo_planning.py
 """
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SLO, Exponential, Pareto, ShiftedExponential
 from repro.core.planner import RedundancyPlanner
 
@@ -86,4 +87,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

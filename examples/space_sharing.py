@@ -20,6 +20,7 @@ Run me::
 import numpy as np
 
 from repro.cluster import ClusterEngine, Job, JobPlan, Scenario, simulate_epochs
+from repro.compile_cache import enable_compile_cache
 from repro.core.planner import RedundancyPlanner
 from repro.core.service_time import Pareto
 
@@ -102,6 +103,7 @@ def plan_against_competition() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     one_timeline()
     class_stats()
     plan_against_competition()

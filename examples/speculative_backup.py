@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.cluster import ClusterEngine, Job, Scenario, Speculation, simulate_epochs
 from repro.cluster.runtime import LiveJob, Runtime, replay_trace
+from repro.compile_cache import enable_compile_cache
 from repro.core.planner import RedundancyPlanner
 from repro.core.service_time import Pareto
 
@@ -99,4 +100,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

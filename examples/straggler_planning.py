@@ -9,6 +9,7 @@ Run:  PYTHONPATH=src python examples/straggler_planning.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import traces
 from repro.core.planner import RedundancyPlanner
 
@@ -36,4 +37,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.service_time import ShiftedExponential
 from repro.data import PipelineConfig, SyntheticLM
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

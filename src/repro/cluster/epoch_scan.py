@@ -320,6 +320,17 @@ class _RunnerCfg:
 # --------------------------------------------------------------------------
 
 
+def _put(x, i, v, on):
+    """``x.at[i].set(v)`` where ``on`` and ``0 <= i < len(x)``, else ``x``.
+
+    A select over the whole row, not a scatter: a TPU v5e compile of the
+    vmapped lanes at 1024+ lanes per device flattens the per-lane scalar
+    scatters over the lane axis, and those runs left most lanes' jobs
+    unfinished while 512 lanes and the CPU were right.
+    """
+    return jnp.where((jnp.arange(x.shape[0]) == i) & on, v, x)
+
+
 def _build_lane(cfg: _RunnerCfg):
     n, jobs_pad, ev_pad = cfg.n, cfg.jobs_pad, cfg.ev_pad
     replan = cfg.replan
@@ -491,15 +502,11 @@ def _build_lane(cfg: _RunnerCfg):
             td_r = wfree[wstar]
             rk = jnp.clip(st["resc_k"], 0, cfg.resc_cap - 1)
             dur_r = tau_resc[rk, tgt] * batch_scale(st["job_b"]) / speeds[wstar]
-            # gated writes: the index goes out of bounds when the action is
-            # off, and jax scatters drop out-of-bounds updates
-            i_tgt = jnp.where(can_r, tgt, n)
-            i_slot = jnp.where(can_r, n + tgt, n_slots)
-            st["rb_w"] = st["rb_w"].at[i_tgt].set(wstar.astype(jnp.int32))
-            st["rp_start"] = st["rp_start"].at[i_slot].set(td_r)
-            st["rp_end"] = st["rp_end"].at[i_slot].set(td_r + dur_r)
-            st["rp_live"] = st["rp_live"].at[i_slot].set(True)
-            st["resc_pending"] = st["resc_pending"].at[i_tgt].set(False)
+            st["rb_w"] = _put(st["rb_w"], tgt, wstar.astype(jnp.int32), can_r)
+            st["rp_start"] = _put(st["rp_start"], n + tgt, td_r, can_r)
+            st["rp_end"] = _put(st["rp_end"], n + tgt, td_r + dur_r, can_r)
+            st["rp_live"] = _put(st["rp_live"], n + tgt, True, can_r)
+            st["resc_pending"] = _put(st["resc_pending"], tgt, False, can_r)
             st["n_resc"] = st["n_resc"] + can_r
             st["resc_k"] = st["resc_k"] + can_r
 
@@ -571,13 +578,10 @@ def _build_lane(cfg: _RunnerCfg):
                     * batch_scale(st["job_b"])
                     / speeds[w_s]
                 )
-                i_sl = jnp.where(do_l, 2 * n + b_s, n_slots)
-                st["sb_w"] = st["sb_w"].at[jnp.where(do_l, b_s, n)].set(
-                    w_s.astype(jnp.int32)
-                )
-                st["rp_start"] = st["rp_start"].at[i_sl].set(t_spec)
-                st["rp_end"] = st["rp_end"].at[i_sl].set(t_spec + dur_s)
-                st["rp_live"] = st["rp_live"].at[i_sl].set(True)
+                st["sb_w"] = _put(st["sb_w"], b_s, w_s.astype(jnp.int32), do_l)
+                st["rp_start"] = _put(st["rp_start"], 2 * n + b_s, t_spec, do_l)
+                st["rp_end"] = _put(st["rp_end"], 2 * n + b_s, t_spec + dur_s, do_l)
+                st["rp_live"] = _put(st["rp_live"], 2 * n + b_s, True, do_l)
                 st["spec_used"] = st["spec_used"] + do_l
                 st["n_spec"] = st["n_spec"] + do_l
                 st["spec_k"] = st["spec_k"] + do_l
@@ -635,7 +639,7 @@ def _build_lane(cfg: _RunnerCfg):
             st["t_cursor"] = jnp.maximum(
                 st["t_cursor"], jnp.maximum(t_new, jnp.where(completes, fin, -inf))
             )
-            st["fins"] = st["fins"].at[jnp.where(completes, qa, jobs_pad)].set(fin)
+            st["fins"] = _put(st["fins"], qa, fin, completes)
             st["job_active"] = st["job_active"] & ~(all_done & ~can_r)
             st["resc_pending"] = st["resc_pending"] & ~completes
 
@@ -719,10 +723,9 @@ def _build_lane(cfg: _RunnerCfg):
             st["job_active"] = st["job_active"] | can_d
             st["job_b"] = jnp.where(can_d, b, st["job_b"])
             st["q_active"] = jnp.where(can_d, st["q"], st["q_active"])
-            i_q = jnp.where(can_d, q, jobs_pad)
-            st["starts"] = st["starts"].at[i_q].set(td)
+            st["starts"] = _put(st["starts"], q, td, can_d)
             if cfg.full_outputs:
-                st["br"] = st["br"].at[i_q].set((b << 16 | r).astype(jnp.int32))
+                st["br"] = _put(st["br"], q, (b << 16 | r).astype(jnp.int32), can_d)
             st["q"] = st["q"] + can_d
             if spec is not None:
                 # per-job policy state resets at dispatch (a fresh _JobExec)
@@ -745,7 +748,7 @@ def _build_lane(cfg: _RunnerCfg):
             do_fail = act & ~up & was
             do_join = act & up & ~was
             # a fail flips alive to False (= up), a join to True (= up)
-            st["alive"] = st["alive"].at[jnp.where(do_fail | do_join, w, n)].set(up)
+            st["alive"] = _put(st["alive"], w, up, do_fail | do_join)
             kill = st["rp_live"] & (rp_w == w) & do_fail
             st["busy"] = st["busy"] + jnp.where(kill, t_ev - st["rp_start"], 0.0).sum()
             live3 = st["rp_live"] & ~kill
@@ -769,9 +772,7 @@ def _build_lane(cfg: _RunnerCfg):
                 jnp.where(do_b & jnp.isfinite(t_ev), jnp.maximum(t_ev, 0.0), -inf),
             )
             if cfg.full_outputs:
-                st["ep_times"] = st["ep_times"].at[
-                    jnp.where(do_fail | do_join, e, ev_pad)
-                ].set(t_ev)
+                st["ep_times"] = _put(st["ep_times"], e, t_ev, do_fail | do_join)
             st["e"] = jnp.minimum(e + do_b, ev_pad - 1)
             return st
 
@@ -939,8 +940,9 @@ def _build_space_lane(cfg: _RunnerCfg):
             kill_c = on_win & st["rp_cancel"]
             st["busy"] = st["busy"] + jnp.where(kill_c, win_r - st["rp_start"], 0.0).sum()
             st["saved"] = st["saved"] + jnp.where(kill_c, st["rp_end"] - win_r, 0.0).sum()
-            st["w_avail"] = st["w_avail"].at[jnp.where(kill_c, rp_w, 2 * n)].set(
-                jnp.where(kill_c, win_r, 0.0)
+            # trash slot n takes the writes of replicas not killed
+            st["w_avail"] = (
+                jnp.append(st["w_avail"], 0.0).at[jnp.where(kill_c, rp_w, n)].set(win_r)[:n]
             )
             # non-cancel replicas retire individually at their own end
             retire = st["rp_live"] & ~st["rp_cancel"] & (st["rp_end"] <= t_next)
@@ -957,8 +959,8 @@ def _build_space_lane(cfg: _RunnerCfg):
             # -- job bookkeeping: wins decrement the owner's open count
             segj = st["seg_job"]
             i_new = jnp.where(newly, jnp.clip(segj, 0, J - 1), J)
-            st["job_left"] = st["job_left"].at[i_new].add(-1)
-            st["job_fin"] = st["job_fin"].at[i_new].max(win)
+            st["job_left"] = jnp.append(st["job_left"], 0).at[i_new].add(-1)[:J]
+            st["job_fin"] = jnp.append(st["job_fin"], -jnp.inf).at[i_new].max(win)[:J]
             st["seg_job"] = jnp.where(newly, J, segj)  # freed at the win
             st["resc_pending"] = st["resc_pending"] & ~newly
             comp = st["dispatched"] & (st["job_left"] == 0) & ~st["recorded"]
@@ -1009,20 +1011,18 @@ def _build_space_lane(cfg: _RunnerCfg):
                 * bscale(jnp.maximum(st["job_b"][j_star], 1))
                 / speeds[w_star]
             )
-            i_w = jnp.where(can_r, w_star, n)
-            i_s = jnp.where(can_r, s_star, n)
-            i_slot = jnp.where(can_r, n + s_star, 2 * n)
-            st["rb_w"] = st["rb_w"].at[i_s].set(w_star.astype(jnp.int32))
-            st["rp_start"] = st["rp_start"].at[i_slot].set(serve_min)
-            st["rp_end"] = st["rp_end"].at[i_slot].set(serve_min + dur_r)
-            st["rp_live"] = st["rp_live"].at[i_slot].set(True)
-            st["rp_cancel"] = st["rp_cancel"].at[i_slot].set(cancel_tab[j_star])
-            st["resc_pending"] = st["resc_pending"].at[i_s].set(False)
-            st["w_job"] = st["w_job"].at[i_w].set(j_star.astype(jnp.int32))
-            st["w_avail"] = st["w_avail"].at[i_w].set(serve_min + dur_r)
+            st["rb_w"] = _put(st["rb_w"], s_star, w_star.astype(jnp.int32), can_r)
+            st["rp_start"] = _put(st["rp_start"], n + s_star, serve_min, can_r)
+            st["rp_end"] = _put(st["rp_end"], n + s_star, serve_min + dur_r, can_r)
+            st["rp_live"] = _put(st["rp_live"], n + s_star, True, can_r)
+            st["rp_cancel"] = _put(st["rp_cancel"], n + s_star, cancel_tab[j_star], can_r)
+            st["resc_pending"] = _put(st["resc_pending"], s_star, False, can_r)
+            st["w_job"] = _put(st["w_job"], w_star, j_star.astype(jnp.int32), can_r)
+            st["w_avail"] = _put(st["w_avail"], w_star, serve_min + dur_r, can_r)
             # speed-weighted load (duration / speed), same op order as the
             # engine's _assign so f64 lanes replay placement bit-for-bit
-            st["w_load"] = st["w_load"].at[i_w].add(dur_r / speeds[w_star])
+            w_load_new = st["w_load"][w_star] + dur_r / speeds[w_star]
+            st["w_load"] = _put(st["w_load"], w_star, w_load_new, can_r)
             st["n_resc"] = st["n_resc"] + can_r
             st["resc_k"] = st["resc_k"] + can_r
 
@@ -1088,13 +1088,13 @@ def _build_space_lane(cfg: _RunnerCfg):
             st["seg_job"] = jnp.where(
                 can_d & segfree & (seg_rank < b_d), q_star.astype(jnp.int32), st["seg_job"]
             )
-            i_q = jnp.where(can_d, q_star, jobs_pad)
-            st["starts"] = st["starts"].at[i_q].set(td)
-            st["dispatched"] = st["dispatched"].at[i_q].set(True)
-            st["job_left"] = st["job_left"].at[i_q].set(b_d)
-            st["job_b"] = st["job_b"].at[i_q].set(b_d)
+            st["starts"] = _put(st["starts"], q_star, td, can_d)
+            st["dispatched"] = _put(st["dispatched"], q_star, True, can_d)
+            st["job_left"] = _put(st["job_left"], q_star, b_d, can_d)
+            st["job_b"] = _put(st["job_b"], q_star, b_d, can_d)
             if cfg.full_outputs:
-                st["br"] = st["br"].at[i_q].set((b_d << 16 | r_d).astype(jnp.int32))
+                br = (b_d << 16 | r_d).astype(jnp.int32)
+                st["br"] = _put(st["br"], q_star, br, can_d)
 
             # -- otherwise apply one fail/join event (sim-over gated)
             do_b = ~can_r & ~can_d
@@ -1105,7 +1105,7 @@ def _build_space_lane(cfg: _RunnerCfg):
             was = st["alive"][w]
             do_fail = act & ~up & was
             do_join = act & up & ~was
-            st["alive"] = st["alive"].at[jnp.where(do_fail | do_join, w, n)].set(up)
+            st["alive"] = _put(st["alive"], w, up, do_fail | do_join)
             kill = st["rp_live"] & (rp_w == w) & do_fail
             st["busy"] = st["busy"] + jnp.where(kill, t_ev - st["rp_start"], 0.0).sum()
             live3 = st["rp_live"] & ~kill
@@ -1116,18 +1116,16 @@ def _build_space_lane(cfg: _RunnerCfg):
             st["resc_pending"] = st["resc_pending"] | lost
             st["resc_t"] = jnp.where(lost, t_ev, st["resc_t"])
             st["g_s"] = jnp.where(do_fail & (widx == w), n, st["g_s"])
-            st["w_job"] = st["w_job"].at[jnp.where(do_fail | do_join, w, n)].set(J)
-            st["w_avail"] = st["w_avail"].at[jnp.where(do_fail, w, n)].set(jnp.inf)
-            st["w_avail"] = st["w_avail"].at[jnp.where(do_join, w, n)].set(t_ev)
+            st["w_job"] = _put(st["w_job"], w, J, do_fail | do_join)
+            st["w_avail"] = _put(st["w_avail"], w, jnp.inf, do_fail)
+            st["w_avail"] = _put(st["w_avail"], w, t_ev, do_join)
             st["n_fail"] = st["n_fail"] + do_fail
             st["t_epoch"] = jnp.maximum(
                 st["t_epoch"],
                 jnp.where(do_b & jnp.isfinite(t_ev), jnp.maximum(t_ev, 0.0), -inf),
             )
             if cfg.full_outputs:
-                st["ep_times"] = st["ep_times"].at[
-                    jnp.where(do_fail | do_join, e, ev_pad)
-                ].set(t_ev)
+                st["ep_times"] = _put(st["ep_times"], e, t_ev, do_fail | do_join)
             st["e"] = jnp.minimum(e + do_b, ev_pad - 1)
             return st
 
@@ -1272,22 +1270,20 @@ def _get_runner(cfg: _RunnerCfg):
     if cfg.devices > 1:
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from ..distributed.compat import shard_map
-
         mesh = Mesh(np.array(jax.devices()[: cfg.devices]), ("lanes",))
         # check_vma=False: the early-exit while_loop has no replication rule,
         # and every lane is independent anyway (out_specs split the lane axis)
-        fn = shard_map(
+        fn = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P("lanes"),) * 7 + (P(),) * 9,
             out_specs=P("lanes"),
             check_vma=False,
         )
-    # donating the big per-lane buffers lets XLA reuse them for the loop
-    # carry; CPU does not support donation (it would only warn), so gate it
-    donate = () if jax.default_backend() == "cpu" else (0, 1, 2, 3, 4, 5, 6)
-    runner = jax.jit(fn, donate_argnums=donate)
+    # no buffer donation: XLA can only alias a donated input to an output of
+    # the same shape, and no per-lane draw or churn buffer has one (a TPU
+    # compile warns that the donated buffers were not usable)
+    runner = jax.jit(fn)
     _RUNNERS[cfg] = runner
     return runner
 

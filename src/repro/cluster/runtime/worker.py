@@ -269,6 +269,9 @@ def spawn_worker_subprocess(host: str, port: int) -> subprocess.Popen:
     here = os.path.abspath(__file__)
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(here))))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+    # payloads run on the host; a worker that loaded the accelerator would
+    # contend with a parent holding the chip (one process per chip)
+    env["JAX_PLATFORMS"] = "cpu"
     preexec = _pdeathsig_preexec if sys.platform.startswith("linux") else None
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.cluster.runtime", host, str(port)],

@@ -9,14 +9,13 @@ VMEM pass per rep tile on TPU, plus the measurement hook that decides
 whether routing the frontier kernel through it is worth it on the current
 backend.
 
-Measurement (recorded by ``bench_masked_cover``): on this repo's CPU CI the
-kernel only runs under ``interpret=True``, where it loses to the XLA fusion
--- ~10x at 16k reps and ~60x at 64k reps x (16, 16) grids (interpret
-overhead scales with the grid) -- so :func:`repro.cluster.vectorized` keeps
-the jnp path unless ``REPRO_PALLAS_COVER=1`` is set *and* a TPU backend is
-present.  On TPU the fused pass saves one VMEM round-trip of the
-``(reps, B_pad)`` batch-min intermediate; re-run ``bench_masked_cover()``
-there before flipping the default.
+Measurement (recorded by ``bench_masked_cover``): on the CPU the kernel
+runs only under ``interpret=True``, where it loses to the XLA fusion --
+~10x at 16k reps and ~60x at 64k reps x (16, 16) grids (interpret overhead
+scales with the grid).  Compiled on one TPU v5e chip it equals the fusion
+and is still slower, ~1.6x at (16384, 16, 16) with (B, r) = (8, 2).  So
+:func:`repro.cluster.vectorized` keeps the jnp path unless
+``REPRO_PALLAS_COVER=1`` is set *and* a TPU backend is present.
 """
 from __future__ import annotations
 
@@ -27,20 +26,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["masked_cover_times", "bench_masked_cover", "pallas_cover_wins"]
 
 
-def _kernel(d_ref, b_ref, r_ref, o_ref):
+def _kernel(b_ref, r_ref, d_ref, o_ref):
     d = d_ref[...]  # (rows, B_pad, r_pad)
-    b, r = b_ref[0], r_ref[0]
-    b_pad, r_pad = d.shape[-2], d.shape[-1]
-    masked = jnp.where(jax.lax.iota(jnp.int32, r_pad)[None, None, :] < r, d, jnp.inf)
+    b, r = b_ref[0], r_ref[0]  # scalar-prefetched candidate (SMEM)
+    masked = jnp.where(jax.lax.broadcasted_iota(jnp.int32, d.shape, 2) < r, d, jnp.inf)
     t_batch = jnp.min(masked, axis=-1)  # (rows, B_pad)
-    t_batch = jnp.where(
-        jax.lax.iota(jnp.int32, b_pad)[None, :] < b, t_batch, -jnp.inf
-    )
-    o_ref[...] = jnp.max(t_batch, axis=-1)
+    b_mask = jax.lax.broadcasted_iota(jnp.int32, t_batch.shape, 1) < b
+    t_batch = jnp.where(b_mask, t_batch, -jnp.inf)
+    o_ref[...] = jnp.max(t_batch, axis=-1, keepdims=True)  # (rows, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -49,38 +47,37 @@ def masked_cover_times(
     n_batches: jax.Array,  # scalar B (traced ok)
     replication: jax.Array,  # scalar r
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused masked ``max_b min_r`` over a padded replica grid.
 
     Semantically identical to ``gang_cover_times(draws, n_batches,
-    replication)``; one VMEM pass per ``block_rows`` tile of reps.
-    ``interpret=True`` (the default) runs everywhere for differential
-    testing; pass ``interpret=False`` on a real TPU backend.
+    replication)``; one VMEM pass per ``block_rows`` tile of reps.  B and r
+    ride in as scalar-prefetch operands (SMEM), and the output is a
+    ``(reps, 1)`` column so its block keeps the TPU's 2-D tiling.  Pass
+    ``interpret=True`` to run the kernel body off-TPU (differential tests).
     """
     reps, b_pad, r_pad = draws.shape
     br = min(block_rows, max(reps, 1))
     pad = (-reps) % br
     if pad:
         draws = jnp.pad(draws, ((0, pad), (0, 0), (0, 0)))
-    grid = ((reps + pad) // br,)
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, b_pad, r_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((br,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((reps + pad,), draws.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=((reps + pad) // br,),
+            in_specs=[pl.BlockSpec((br, b_pad, r_pad), lambda i, b, r: (i, 0, 0))],
+            out_specs=pl.BlockSpec((br, 1), lambda i, b, r: (i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((reps + pad, 1), draws.dtype),
         interpret=interpret,
     )(
-        draws,
         jnp.asarray(n_batches, jnp.int32).reshape(1),
         jnp.asarray(replication, jnp.int32).reshape(1),
+        draws,
     )
-    return out[:reps]
+    return out[:reps, 0]
 
 
 def pallas_cover_wins() -> bool:

@@ -11,8 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .pallas_compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, *, eps: float, plus_one: bool):
@@ -54,7 +53,7 @@ def rms_norm_fused(
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, d), x.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, weight)
     return out[:n].reshape(orig_shape)

@@ -3,19 +3,11 @@ never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # jax < 0.5: meshes have no axis_types concept
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(
-        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
-    )
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

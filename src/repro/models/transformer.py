@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..distributed.axes import shard
-from ..distributed.compat import shard_map
 from .common import cast_for_compute, cross_entropy_loss, dense_init
 from .layers import (
     apply_mrope,
@@ -308,7 +307,7 @@ def _seq_sharded_decode(
 
     rep = P(bt, None, None, None)
     seq = P(bt, ax, None, None)
-    o, ck, cv, pos = shard_map(
+    o, ck, cv, pos = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(rep, rep, rep, seq, seq, P(ax)),

@@ -117,14 +117,13 @@ import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_allreduce_mean
-from repro.distributed.compat import shard_map
 from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((8,), ("pod",))
 x = jax.random.normal(jax.random.key(0), (8, 64, 64))
 ef = jnp.zeros_like(x)
 
-@partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
 def reduce_fn(xs, efs):
     m, e = compressed_allreduce_mean(xs[0], efs[0], "pod")
     return m[None], e[None]

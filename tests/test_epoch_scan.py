@@ -488,6 +488,24 @@ def test_rep_chunk_bit_identical():
         simulate_epochs(d, 6, 3, np.zeros(4), 8, rep_chunk=0)
 
 
+def test_gated_write_sets_only_when_on_and_in_range():
+    """The lanes' gated scalar write: set when on and in range, else a no-op
+    (out-of-range indices included), lane by lane under vmap."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.cluster.epoch_scan import _put
+
+    x = jnp.arange(4.0)
+    assert _put(x, 2, 9.0, True).tolist() == [0.0, 1.0, 9.0, 3.0]
+    for i, on in [(2, False), (4, True), (-1, True)]:
+        assert _put(x, i, 9.0, on).tolist() == x.tolist()
+    lanes = jnp.tile(x, (3, 1))
+    got = jax.vmap(_put)(lanes, jnp.array([1, 4, 3]), jnp.array([7.0, 8.0, 9.0]),
+                         jnp.array([True, True, False]))
+    assert got.tolist() == [[0.0, 7.0, 2.0, 3.0], x.tolist(), x.tolist()]
+
+
 def test_sharded_devices_match_single_device():
     """devices > 1 shards the lane grid via shard_map; per-lane seed
     derivation keeps the results exactly equal to single-device runs."""

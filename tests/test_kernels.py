@@ -139,7 +139,9 @@ def test_masked_cover_matches_oracle():
 
     draws = jax.random.exponential(jax.random.key(3), (37, 6, 4))
     for b, r in [(1, 1), (3, 2), (6, 4), (2, 4), (6, 1)]:
-        got = masked_cover_times(draws, jnp.int32(b), jnp.int32(r), block_rows=16)
+        got = masked_cover_times(
+            draws, jnp.int32(b), jnp.int32(r), block_rows=16, interpret=True
+        )
         want = gang_cover_times(draws, b, r)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
     # the measurement hook runs everywhere and reports honestly: interpret
