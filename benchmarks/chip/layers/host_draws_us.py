@@ -1,0 +1,7 @@
+"""Host draw prep per job-rep: self time of the ``draws.*`` spans of the last day (us/job)."""
+from yardstick.program_record import per_job_rep
+
+
+def read(reduced, units):
+    s = per_job_rep(reduced, "draws")
+    return None if s is None else 1e6 * s

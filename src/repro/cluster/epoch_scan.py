@@ -84,6 +84,7 @@ from jax.scipy.special import gammaln
 
 from ..core.analysis import divisor_table, harmonic_tables
 from ..core.service_time import ServiceTime
+from ..spans import count, span
 from .scenario import UNSET, Scenario, Speculation, resolve_scenario
 from .scheduler import SCHEDULERS, JobPlan, is_space
 from .workers import ChurnProcess, ChurnSchedule
@@ -1329,8 +1330,9 @@ def _pack_schedule(schedule: Optional[ChurnSchedule], n_lanes: int, ev_pad: int,
         t[: len(schedule)] = np.asarray(schedule.times, np.float64)
         w[: len(schedule)] = np.asarray(schedule.wids, np.int32)
         u[: len(schedule)] = np.asarray(schedule.ups, bool)
-    tile = lambda a: jnp.broadcast_to(jnp.asarray(a), (n_lanes,) + a.shape)  # noqa: E731
-    return tile(t.astype(dtype)), tile(w), tile(u)
+    put = (jnp.asarray(t.astype(dtype)), jnp.asarray(w), jnp.asarray(u))
+    count("h2d.bytes", sum(a.nbytes for a in put))
+    return tuple(jnp.broadcast_to(a, (n_lanes,) + a.shape) for a in put)
 
 
 def _prepare_lanes(dist, n_workers, n_pad, lane_idx, n_real, jobs_pad, ev_pad, resc_cap,
@@ -1353,33 +1355,35 @@ def _prepare_lanes(dist, n_workers, n_pad, lane_idx, n_real, jobs_pad, ev_pad, r
     seed = int(seed)
     sample_churn = churn is not None and churn.fail_rate > 0.0 and pairs > 0
     need_resc = sample_churn or (churn_schedule is not None and len(churn_schedule))
-    tau = np.ones((n_lanes, jobs_pad, n_pad), dtype)
-    tau_resc = np.ones((n_lanes, resc_cap, n_pad), dtype)
-    tau_spec = np.ones((n_lanes, max(spec_cap, 1), n_pad), dtype)
-    horizon = np.full(n_lanes, np.inf)
-    if sample_churn:
-        ev_t = np.full((n_lanes, ev_pad), np.inf, dtype)
-        ev_w = np.full((n_lanes, ev_pad), -1, np.int32)
-        ev_up = np.zeros((n_lanes, ev_pad), bool)
-    for i, lane in enumerate(lane_idx[:n_real]):
-        rng = np.random.default_rng(np.random.SeedSequence((seed, int(lane))))
-        tau[i] = dist.sample_np(rng, (jobs_pad, n_pad))
-        if need_resc:
-            tau_resc[i] = dist.sample_np(rng, (resc_cap, n_pad))
-        if spec_cap:
-            tau_spec[i] = dist.sample_np(rng, (spec_cap, n_pad))
+    with span("draws.lanes"):
+        tau = np.ones((n_lanes, jobs_pad, n_pad), dtype)
+        tau_resc = np.ones((n_lanes, resc_cap, n_pad), dtype)
+        tau_spec = np.ones((n_lanes, max(spec_cap, 1), n_pad), dtype)
+        horizon = np.full(n_lanes, np.inf)
         if sample_churn:
-            t, w, u, horizon[i] = _sample_churn_np(rng, churn, n_workers, pairs)
-            k = min(len(t), ev_pad)
-            ev_t[i, :k], ev_w[i, :k], ev_up[i, :k] = t[:k], w[:k], u[:k]
-    if not sample_churn:
-        ev_t, ev_w, ev_up = _pack_schedule(churn_schedule, n_lanes, ev_pad, dtype)
-    else:
-        ev_t, ev_w, ev_up = jnp.asarray(ev_t), jnp.asarray(ev_w), jnp.asarray(ev_up)
-    return (
-        jnp.asarray(tau), jnp.asarray(tau_resc), jnp.asarray(tau_spec),
-        ev_t, ev_w, ev_up, horizon,
-    )
+            ev_t = np.full((n_lanes, ev_pad), np.inf, dtype)
+            ev_w = np.full((n_lanes, ev_pad), -1, np.int32)
+            ev_up = np.zeros((n_lanes, ev_pad), bool)
+        for i, lane in enumerate(lane_idx[:n_real]):
+            rng = np.random.default_rng(np.random.SeedSequence((seed, int(lane))))
+            tau[i] = dist.sample_np(rng, (jobs_pad, n_pad))
+            if need_resc:
+                tau_resc[i] = dist.sample_np(rng, (resc_cap, n_pad))
+            if spec_cap:
+                tau_spec[i] = dist.sample_np(rng, (spec_cap, n_pad))
+            if sample_churn:
+                t, w, u, horizon[i] = _sample_churn_np(rng, churn, n_workers, pairs)
+                k = min(len(t), ev_pad)
+                ev_t[i, :k], ev_w[i, :k], ev_up[i, :k] = t[:k], w[:k], u[:k]
+    with span("xfer.put"):
+        if not sample_churn:
+            ev = _pack_schedule(churn_schedule, n_lanes, ev_pad, dtype)
+        else:
+            ev = (jnp.asarray(ev_t), jnp.asarray(ev_w), jnp.asarray(ev_up))
+            count("h2d.bytes", sum(a.nbytes for a in ev))
+        taus = (jnp.asarray(tau), jnp.asarray(tau_resc), jnp.asarray(tau_spec))
+        count("h2d.bytes", sum(a.nbytes for a in taus))
+    return (*taus, *ev, horizon)
 
 
 def _shapes(n_workers, n_jobs, churn, churn_schedule, pairs, speculation=None):
@@ -1435,14 +1439,11 @@ def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, se
         dist, n_workers, cfg.n, idx, lanes, cfg.jobs_pad, cfg.ev_pad, cfg.resc_cap,
         seed, churn, churn_schedule, pairs, dtype, spec_cap=spec_cap,
     )
+    # (host value, device dtype) of the runner's remaining inputs
     if cfg.scheduler is not None:
         req_tab, b_tab, cancel_tab, default_req = space_tabs
-        tail = (
-            jnp.asarray(req_tab, jnp.int32),
-            jnp.asarray(b_tab, jnp.int32),
-            jnp.asarray(cancel_tab, bool),
-            jnp.int32(default_req),
-        )
+        tail = ((req_tab, jnp.int32), (b_tab, jnp.int32), (cancel_tab, bool),
+                (default_req, jnp.int32))
     else:
         div_tab, (h1, h2) = divisor_table(n_workers), harmonic_tables(n_workers)
         div_pad = np.zeros((cfg.n + 1, _pow2(div_tab.shape[1])), div_tab.dtype)
@@ -1450,29 +1451,18 @@ def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, se
         h_pad = np.zeros(cfg.n + 1)
         hp1, hp2 = h_pad.copy(), h_pad.copy()
         hp1[: len(h1)], hp2[: len(h2)] = h1, h2
-        tail = (
-            jnp.asarray(replan.blend if replan is not None else 0.5, dtype),
-            jnp.asarray(div_pad),
-            jnp.asarray(hp1, dtype),
-            jnp.asarray(hp2, dtype),
-        )
-    runner = _get_runner(cfg)
-    out = runner(
-        tau,
-        tau_resc,
-        tau_spec,
-        ev_t,
-        ev_w,
-        ev_up,
-        jnp.asarray(b0, jnp.int32),
-        jnp.asarray(arrivals_pad, dtype),
-        jnp.asarray(speeds, dtype),
-        jnp.int32(n_workers),
-        jnp.int32(n_jobs_real),
-        jnp.asarray(n_tasks, dtype),
-        *tail,
-    )
-    res = {k: np.asarray(v)[:lanes] for k, v in out.items()}
+        tail = ((replan.blend if replan is not None else 0.5, dtype), (div_pad, None),
+                (hp1, dtype), (hp2, dtype))
+    head = ((b0, jnp.int32), (arrivals_pad, dtype), (speeds, dtype), (n_workers, jnp.int32),
+            (n_jobs_real, jnp.int32), (n_tasks, dtype))
+    with span("xfer.put"):
+        rest = tuple(jnp.asarray(x, t) for x, t in head + tail)
+        count("h2d.bytes", sum(a.nbytes for a in rest))
+    out = _get_runner(cfg)(tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, *rest)
+    with span("xfer.get"):
+        with span("wait.device"):
+            jax.block_until_ready(out)
+        res = {k: np.asarray(v)[:lanes] for k, v in out.items()}
     res["churn_horizon"] = horizon[:lanes]  # host-side, inf unless churn sampled
     return res
 
@@ -1715,155 +1705,156 @@ def simulate_epochs(
     one validated ``scenario=Scenario(...)``; the loose keyword forms keep
     working behind a :class:`DeprecationWarning` shim.
     """
-    sc = resolve_scenario(
-        scenario,
-        {
-            "cancel_redundant": cancel_redundant,
-            "size_dependent": size_dependent,
-            "n_tasks": n_tasks,
-            "speeds": speeds,
-            "churn": churn,
-            "churn_schedule": churn_schedule,
-            "churn_pairs_per_worker": churn_pairs_per_worker,
-            "replan": replan,
-            "speculation": speculation,
-            "scheduler": scheduler,
-            "workers_per_job": workers_per_job,
-            "job_plans": job_plans,
-            "dtype": dtype,
-            "rep_chunk": rep_chunk,
-            "devices": devices,
-            "outputs": outputs,
-        },
-        where="simulate_epochs",
-    )
-    dist = dist if dist is not None else sc.dist
-    n_workers = int(n_workers if n_workers is not None else sc.n_workers)
-    n_batches = n_batches if n_batches is not None else sc.n_batches
-    if dist is None or arrivals is None or n_reps is None:
-        raise ValueError("simulate_epochs needs dist (or scenario.dist), arrivals, and n_reps")
-    arrivals = np.asarray(arrivals, dtype=np.float64)
-    if arrivals.ndim != 1 or arrivals.size == 0:
-        raise ValueError("arrivals must be a non-empty 1-D array")
-    if (np.diff(arrivals) < 0).any():
-        raise ValueError("arrivals must be sorted (FIFO order)")
-    _check_arrival_span(arrivals, sc.dtype)
-    if n_batches is not None and not (1 <= int(n_batches) <= n_workers):
-        raise ValueError(f"n_batches must lie in [1, {n_workers}] or be None")
-    speeds = _validate_common(n_workers, sc)
-    cancel_redundant = sc.cancel_redundant
-    size_dependent = sc.size_dependent
-    churn = sc.churn
-    churn_schedule = sc.churn_schedule
-    churn_pairs_per_worker = sc.churn_pairs_per_worker
-    replan = sc.replan
-    speculation = sc.speculation
-    scheduler = sc.scheduler_name
-    workers_per_job = sc.workers_per_job
-    job_plans = sc.job_plans
-    dtype = sc.dtype
-    rep_chunk = sc.rep_chunk
-    devices = sc.devices
-    n_tasks = sc.n_tasks if sc.n_tasks is not None else n_workers
-    n_jobs = arrivals.size
-    churn_pairs_per_worker = _resolve_churn_pairs(
-        churn_pairs_per_worker, dist, churn, n_workers, n_batches, n_tasks,
-        size_dependent, speeds, arrivals, n_jobs,
-    )
-    n_pad, jobs_pad, ev_pad, resc_cap, n_chunks = _shapes(
-        n_workers, n_jobs, churn, churn_schedule, churn_pairs_per_worker,
-        speculation=speculation,
-    )
-    sched_name, tabs = _space_tabs(
-        scheduler, workers_per_job, job_plans, n_jobs, jobs_pad, n_workers,
-        cancel_redundant, replan,
-    )
-    stream_mode = sc.outputs == "stream"
-    cfg = _RunnerCfg(
-        n_pad, jobs_pad, ev_pad, resc_cap, n_chunks,
-        bool(cancel_redundant), bool(size_dependent), replan, dtype, int(devices),
-        full_outputs=not stream_mode,
-        stream=stream_mode,
-        scheduler=sched_name,
-        spec=speculation,
-    )
-    arrivals_pad = np.concatenate([arrivals, np.full(jobs_pad - n_jobs, np.inf)])
-    b0_val = 0 if n_batches is None else int(n_batches)
-    chunks = []
-    for lo, hi in _rep_slices(int(n_reps), rep_chunk):
-        chunks.append(
-            _run_lanes(
-                dist, cfg, n_workers, np.arange(lo, hi), np.full(hi - lo, b0_val, np.int32),
-                arrivals_pad, n_jobs, seed, speeds, churn, churn_schedule,
-                churn_pairs_per_worker, n_tasks, replan, space_tabs=tabs,
-            )
+    with span("entry.simulate_epochs"):
+        sc = resolve_scenario(
+            scenario,
+            {
+                "cancel_redundant": cancel_redundant,
+                "size_dependent": size_dependent,
+                "n_tasks": n_tasks,
+                "speeds": speeds,
+                "churn": churn,
+                "churn_schedule": churn_schedule,
+                "churn_pairs_per_worker": churn_pairs_per_worker,
+                "replan": replan,
+                "speculation": speculation,
+                "scheduler": scheduler,
+                "workers_per_job": workers_per_job,
+                "job_plans": job_plans,
+                "dtype": dtype,
+                "rep_chunk": rep_chunk,
+                "devices": devices,
+                "outputs": outputs,
+            },
+            where="simulate_epochs",
         )
-    out = {k: np.concatenate([c[k] for c in chunks], axis=0) for k in chunks[0]}
-    sampled = churn is not None and churn.fail_rate > 0.0
-    if stream_mode:
-        from .stream import StreamStats
+        dist = dist if dist is not None else sc.dist
+        n_workers = int(n_workers if n_workers is not None else sc.n_workers)
+        n_batches = n_batches if n_batches is not None else sc.n_batches
+        if dist is None or arrivals is None or n_reps is None:
+            raise ValueError("simulate_epochs needs dist (or scenario.dist), arrivals, and n_reps")
+        arrivals = np.asarray(arrivals, dtype=np.float64)
+        if arrivals.ndim != 1 or arrivals.size == 0:
+            raise ValueError("arrivals must be a non-empty 1-D array")
+        if (np.diff(arrivals) < 0).any():
+            raise ValueError("arrivals must be sorted (FIFO order)")
+        _check_arrival_span(arrivals, sc.dtype)
+        if n_batches is not None and not (1 <= int(n_batches) <= n_workers):
+            raise ValueError(f"n_batches must lie in [1, {n_workers}] or be None")
+        speeds = _validate_common(n_workers, sc)
+        cancel_redundant = sc.cancel_redundant
+        size_dependent = sc.size_dependent
+        churn = sc.churn
+        churn_schedule = sc.churn_schedule
+        churn_pairs_per_worker = sc.churn_pairs_per_worker
+        replan = sc.replan
+        speculation = sc.speculation
+        scheduler = sc.scheduler_name
+        workers_per_job = sc.workers_per_job
+        job_plans = sc.job_plans
+        dtype = sc.dtype
+        rep_chunk = sc.rep_chunk
+        devices = sc.devices
+        n_tasks = sc.n_tasks if sc.n_tasks is not None else n_workers
+        n_jobs = arrivals.size
+        churn_pairs_per_worker = _resolve_churn_pairs(
+            churn_pairs_per_worker, dist, churn, n_workers, n_batches, n_tasks,
+            size_dependent, speeds, arrivals, n_jobs,
+        )
+        n_pad, jobs_pad, ev_pad, resc_cap, n_chunks = _shapes(
+            n_workers, n_jobs, churn, churn_schedule, churn_pairs_per_worker,
+            speculation=speculation,
+        )
+        sched_name, tabs = _space_tabs(
+            scheduler, workers_per_job, job_plans, n_jobs, jobs_pad, n_workers,
+            cancel_redundant, replan,
+        )
+        stream_mode = sc.outputs == "stream"
+        cfg = _RunnerCfg(
+            n_pad, jobs_pad, ev_pad, resc_cap, n_chunks,
+            bool(cancel_redundant), bool(size_dependent), replan, dtype, int(devices),
+            full_outputs=not stream_mode,
+            stream=stream_mode,
+            scheduler=sched_name,
+            spec=speculation,
+        )
+        arrivals_pad = np.concatenate([arrivals, np.full(jobs_pad - n_jobs, np.inf)])
+        b0_val = 0 if n_batches is None else int(n_batches)
+        chunks = []
+        for lo, hi in _rep_slices(int(n_reps), rep_chunk):
+            chunks.append(
+                _run_lanes(
+                    dist, cfg, n_workers, np.arange(lo, hi), np.full(hi - lo, b0_val, np.int32),
+                    arrivals_pad, n_jobs, seed, speeds, churn, churn_schedule,
+                    churn_pairs_per_worker, n_tasks, replan, space_tabs=tabs,
+                )
+            )
+        out = {k: np.concatenate([c[k] for c in chunks], axis=0) for k in chunks[0]}
+        sampled = churn is not None and churn.fail_rate > 0.0
+        if stream_mode:
+            from .stream import StreamStats
 
-        n_unfinished = np.asarray(out["n_unfinished"])
+            n_unfinished = np.asarray(out["n_unfinished"])
+            truncated = None
+            if sampled:
+                # unfinished jobs have no finish stamp: count them as outrunning
+                # the horizon, exactly like the full path's inf finishes do
+                truncated = (np.asarray(out["fin_max"], np.float64) > out["churn_horizon"]) | (
+                    n_unfinished > 0
+                )
+                if truncated.any():
+                    _warn_churn_truncated(truncated, churn_pairs_per_worker)
+            stats = StreamStats(
+                count=np.asarray(out["count"]),
+                resp_sum=np.asarray(out["resp_sum"]),
+                resp_sq=np.asarray(out["resp_sq"]),
+                resp_min=np.asarray(out["resp_min"]),
+                resp_max=np.asarray(out["resp_max"]),
+                comp_sum=np.asarray(out["comp_sum"]),
+                busy_sum=np.asarray(out["worker_seconds"]),
+                saved_sum=np.asarray(out["cancelled_seconds_saved"]),
+                hist=np.asarray(out["hist"]),
+            )
+            return EpochStreamReport(
+                arrivals=arrivals,
+                stats=stats,
+                n_unfinished=n_unfinished,
+                worker_seconds=np.asarray(out["worker_seconds"], np.float64),
+                cancelled_seconds_saved=np.asarray(out["cancelled_seconds_saved"], np.float64),
+                n_worker_failures=np.asarray(out["n_worker_failures"]),
+                n_replicas_rescued=np.asarray(out["n_replicas_rescued"]),
+                n_replans=np.asarray(out["n_replans"]),
+                n_speculative=(
+                    np.asarray(out["n_speculative"]) if "n_speculative" in out else None
+                ),
+                churn_truncated=truncated,
+            )
+        br = np.asarray(out["br"])[:, :n_jobs]
+        finishes = np.asarray(out["finishes"], np.float64)[:, :n_jobs]
         truncated = None
         if sampled:
-            # unfinished jobs have no finish stamp: count them as outrunning
-            # the horizon, exactly like the full path's inf finishes do
-            truncated = (np.asarray(out["fin_max"], np.float64) > out["churn_horizon"]) | (
-                n_unfinished > 0
-            )
+            # a rep whose timeline outran its sampled horizon ran its tail
+            # churn-free (unfinished jobs at inf count as outrunning it)
+            truncated = finishes.max(axis=1) > out["churn_horizon"]
             if truncated.any():
                 _warn_churn_truncated(truncated, churn_pairs_per_worker)
-        stats = StreamStats(
-            count=np.asarray(out["count"]),
-            resp_sum=np.asarray(out["resp_sum"]),
-            resp_sq=np.asarray(out["resp_sq"]),
-            resp_min=np.asarray(out["resp_min"]),
-            resp_max=np.asarray(out["resp_max"]),
-            comp_sum=np.asarray(out["comp_sum"]),
-            busy_sum=np.asarray(out["worker_seconds"]),
-            saved_sum=np.asarray(out["cancelled_seconds_saved"]),
-            hist=np.asarray(out["hist"]),
-        )
-        return EpochStreamReport(
+        return EpochReport(
             arrivals=arrivals,
-            stats=stats,
-            n_unfinished=n_unfinished,
+            starts=np.asarray(out["starts"], np.float64)[:, :n_jobs],
+            finishes=finishes,
+            n_batches_used=br >> 16,
+            replication_used=br & 0xFFFF,
             worker_seconds=np.asarray(out["worker_seconds"], np.float64),
             cancelled_seconds_saved=np.asarray(out["cancelled_seconds_saved"], np.float64),
             n_worker_failures=np.asarray(out["n_worker_failures"]),
             n_replicas_rescued=np.asarray(out["n_replicas_rescued"]),
             n_replans=np.asarray(out["n_replans"]),
+            epoch_times=np.asarray(out["epoch_times"], np.float64),
             n_speculative=(
                 np.asarray(out["n_speculative"]) if "n_speculative" in out else None
             ),
             churn_truncated=truncated,
         )
-    br = np.asarray(out["br"])[:, :n_jobs]
-    finishes = np.asarray(out["finishes"], np.float64)[:, :n_jobs]
-    truncated = None
-    if sampled:
-        # a rep whose timeline outran its sampled horizon ran its tail
-        # churn-free (unfinished jobs at inf count as outrunning it)
-        truncated = finishes.max(axis=1) > out["churn_horizon"]
-        if truncated.any():
-            _warn_churn_truncated(truncated, churn_pairs_per_worker)
-    return EpochReport(
-        arrivals=arrivals,
-        starts=np.asarray(out["starts"], np.float64)[:, :n_jobs],
-        finishes=finishes,
-        n_batches_used=br >> 16,
-        replication_used=br & 0xFFFF,
-        worker_seconds=np.asarray(out["worker_seconds"], np.float64),
-        cancelled_seconds_saved=np.asarray(out["cancelled_seconds_saved"], np.float64),
-        n_worker_failures=np.asarray(out["n_worker_failures"]),
-        n_replicas_rescued=np.asarray(out["n_replicas_rescued"]),
-        n_replans=np.asarray(out["n_replans"]),
-        epoch_times=np.asarray(out["epoch_times"], np.float64),
-        n_speculative=(
-            np.asarray(out["n_speculative"]) if "n_speculative" in out else None
-        ),
-        churn_truncated=truncated,
-    )
 
 
 def frontier_job_times_dynamic(
@@ -1918,99 +1909,100 @@ def frontier_job_times_dynamic(
     planner's per-job-times source, so it always runs the reduced-output
     lanes (no per-event/per-plan buffers) and never the streaming fold.
     """
-    sc = resolve_scenario(
-        scenario,
-        {
-            "cancel_redundant": cancel_redundant,
-            "size_dependent": size_dependent,
-            "n_tasks": n_tasks,
-            "speeds": speeds,
-            "churn": churn,
-            "churn_schedule": churn_schedule,
-            "churn_pairs_per_worker": churn_pairs_per_worker,
-            "replan": replan,
-            "speculation": speculation,
-            "scheduler": scheduler,
-            "workers_per_job": workers_per_job,
-            "job_plans": job_plans,
-            "dtype": dtype,
-            "rep_chunk": rep_chunk,
-            "devices": devices,
-        },
-        where="frontier_job_times_dynamic",
-    )
-    dist = dist if dist is not None else sc.dist
-    n_workers = int(n_workers if n_workers is not None else sc.n_workers)
-    if dist is None or candidates is None or n_reps is None:
-        raise ValueError(
-            "frontier_job_times_dynamic needs dist (or scenario.dist), candidates, and n_reps"
+    with span("entry.frontier_dynamic"):
+        sc = resolve_scenario(
+            scenario,
+            {
+                "cancel_redundant": cancel_redundant,
+                "size_dependent": size_dependent,
+                "n_tasks": n_tasks,
+                "speeds": speeds,
+                "churn": churn,
+                "churn_schedule": churn_schedule,
+                "churn_pairs_per_worker": churn_pairs_per_worker,
+                "replan": replan,
+                "speculation": speculation,
+                "scheduler": scheduler,
+                "workers_per_job": workers_per_job,
+                "job_plans": job_plans,
+                "dtype": dtype,
+                "rep_chunk": rep_chunk,
+                "devices": devices,
+            },
+            where="frontier_job_times_dynamic",
         )
-    bs = np.asarray(list(candidates), dtype=np.int32)
-    if bs.size == 0:
-        raise ValueError("need at least one candidate B")
-    if (bs < 1).any() or (bs > n_workers).any():
-        raise ValueError(f"candidates must lie in [1, {n_workers}], got {bs.tolist()}")
-    speeds = _validate_common(n_workers, sc)
-    cancel_redundant = sc.cancel_redundant
-    size_dependent = sc.size_dependent
-    churn = sc.churn
-    churn_schedule = sc.churn_schedule
-    churn_pairs_per_worker = sc.churn_pairs_per_worker
-    replan = sc.replan
-    speculation = sc.speculation
-    scheduler = sc.scheduler_name
-    workers_per_job = sc.workers_per_job
-    job_plans = sc.job_plans
-    dtype = sc.dtype
-    rep_chunk = sc.rep_chunk
-    devices = sc.devices
-    n_tasks = sc.n_tasks if sc.n_tasks is not None else n_workers
-    n_jobs = sc.jobs_per_stream if n_jobs is None else n_jobs
-    n_jobs = max(1, min(int(n_jobs), int(n_reps)))
-    s = math.ceil(n_reps / n_jobs)
-    c = len(bs)
-    # auto-size against the widest-scale candidate (smallest B): its jobs
-    # run longest, so its streams are the ones that outlive short horizons
-    churn_pairs_per_worker = _resolve_churn_pairs(
-        churn_pairs_per_worker, dist, churn, n_workers, int(bs.min()), n_tasks,
-        size_dependent, speeds, None, n_jobs,
-    )
-    n_pad, jobs_pad, ev_pad, resc_cap, n_chunks = _shapes(
-        n_workers, n_jobs, churn, churn_schedule, churn_pairs_per_worker,
-        speculation=speculation,
-    )
-    sched_name, tabs = _space_tabs(
-        scheduler, workers_per_job, job_plans, n_jobs, jobs_pad, n_workers,
-        cancel_redundant, replan,
-    )
-    cfg = _RunnerCfg(
-        n_pad, jobs_pad, ev_pad, resc_cap, n_chunks,
-        bool(cancel_redundant), bool(size_dependent), replan, dtype, int(devices),
-        full_outputs=False,  # planning reads starts/finishes only
-        scheduler=sched_name,
-        spec=speculation,
-    )
-    arrivals_pad = np.concatenate([np.zeros(n_jobs), np.full(jobs_pad - n_jobs, np.inf)])
-    chunks = []
-    trunc = np.zeros(0, bool)
-    for lo, hi in _rep_slices(s, rep_chunk):
-        # lane (ci, rep) has global index ci * s + rep: chunking over reps
-        # keeps every lane's SeedSequence identity, hence its draws, unchanged
-        lane_idx = (np.arange(c)[:, None] * s + np.arange(lo, hi)[None, :]).ravel()
-        b0 = np.repeat(bs, hi - lo)
-        out = _run_lanes(
-            dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs, seed,
-            speeds, churn, churn_schedule, churn_pairs_per_worker, n_tasks, replan,
-            space_tabs=tabs,
+        dist = dist if dist is not None else sc.dist
+        n_workers = int(n_workers if n_workers is not None else sc.n_workers)
+        if dist is None or candidates is None or n_reps is None:
+            raise ValueError(
+                "frontier_job_times_dynamic needs dist (or scenario.dist), candidates, and n_reps"
+            )
+        bs = np.asarray(list(candidates), dtype=np.int32)
+        if bs.size == 0:
+            raise ValueError("need at least one candidate B")
+        if (bs < 1).any() or (bs > n_workers).any():
+            raise ValueError(f"candidates must lie in [1, {n_workers}], got {bs.tolist()}")
+        speeds = _validate_common(n_workers, sc)
+        cancel_redundant = sc.cancel_redundant
+        size_dependent = sc.size_dependent
+        churn = sc.churn
+        churn_schedule = sc.churn_schedule
+        churn_pairs_per_worker = sc.churn_pairs_per_worker
+        replan = sc.replan
+        speculation = sc.speculation
+        scheduler = sc.scheduler_name
+        workers_per_job = sc.workers_per_job
+        job_plans = sc.job_plans
+        dtype = sc.dtype
+        rep_chunk = sc.rep_chunk
+        devices = sc.devices
+        n_tasks = sc.n_tasks if sc.n_tasks is not None else n_workers
+        n_jobs = sc.jobs_per_stream if n_jobs is None else n_jobs
+        n_jobs = max(1, min(int(n_jobs), int(n_reps)))
+        s = math.ceil(n_reps / n_jobs)
+        c = len(bs)
+        # auto-size against the widest-scale candidate (smallest B): its jobs
+        # run longest, so its streams are the ones that outlive short horizons
+        churn_pairs_per_worker = _resolve_churn_pairs(
+            churn_pairs_per_worker, dist, churn, n_workers, int(bs.min()), n_tasks,
+            size_dependent, speeds, None, n_jobs,
         )
-        fin = np.asarray(out["finishes"], np.float64)
-        start = np.asarray(out["starts"], np.float64)
-        if churn is not None and churn.fail_rate > 0.0:
-            trunc = np.append(trunc, fin[:, :n_jobs].max(axis=1) > out["churn_horizon"])
-        # unfinished jobs (inf start and finish) score inf, not inf - inf
-        with np.errstate(invalid="ignore"):
-            t = np.where(np.isfinite(fin), fin - start, np.inf)
-        chunks.append(t[:, :n_jobs].reshape(c, (hi - lo) * n_jobs))
-    if trunc.any():
-        _warn_churn_truncated(trunc, churn_pairs_per_worker)
-    return np.concatenate(chunks, axis=1)
+        n_pad, jobs_pad, ev_pad, resc_cap, n_chunks = _shapes(
+            n_workers, n_jobs, churn, churn_schedule, churn_pairs_per_worker,
+            speculation=speculation,
+        )
+        sched_name, tabs = _space_tabs(
+            scheduler, workers_per_job, job_plans, n_jobs, jobs_pad, n_workers,
+            cancel_redundant, replan,
+        )
+        cfg = _RunnerCfg(
+            n_pad, jobs_pad, ev_pad, resc_cap, n_chunks,
+            bool(cancel_redundant), bool(size_dependent), replan, dtype, int(devices),
+            full_outputs=False,  # planning reads starts/finishes only
+            scheduler=sched_name,
+            spec=speculation,
+        )
+        arrivals_pad = np.concatenate([np.zeros(n_jobs), np.full(jobs_pad - n_jobs, np.inf)])
+        chunks = []
+        trunc = np.zeros(0, bool)
+        for lo, hi in _rep_slices(s, rep_chunk):
+            # lane (ci, rep) has global index ci * s + rep: chunking over reps
+            # keeps every lane's SeedSequence identity, hence its draws, unchanged
+            lane_idx = (np.arange(c)[:, None] * s + np.arange(lo, hi)[None, :]).ravel()
+            b0 = np.repeat(bs, hi - lo)
+            out = _run_lanes(
+                dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs, seed,
+                speeds, churn, churn_schedule, churn_pairs_per_worker, n_tasks, replan,
+                space_tabs=tabs,
+            )
+            fin = np.asarray(out["finishes"], np.float64)
+            start = np.asarray(out["starts"], np.float64)
+            if churn is not None and churn.fail_rate > 0.0:
+                trunc = np.append(trunc, fin[:, :n_jobs].max(axis=1) > out["churn_horizon"])
+            # unfinished jobs (inf start and finish) score inf, not inf - inf
+            with np.errstate(invalid="ignore"):
+                t = np.where(np.isfinite(fin), fin - start, np.inf)
+            chunks.append(t[:, :n_jobs].reshape(c, (hi - lo) * n_jobs))
+        if trunc.any():
+            _warn_churn_truncated(trunc, churn_pairs_per_worker)
+        return np.concatenate(chunks, axis=1)

@@ -23,6 +23,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from ..spans import span
 from . import analysis
 from .service_time import (
     Empirical,
@@ -338,87 +339,88 @@ class RedundancyPlanner:
             >>> plan.n_batches in (1, 2, 4)
             True
         """
-        from ..cluster.scenario import resolve_scenario
+        with span("entry.plan_cluster"):
+            from ..cluster.scenario import resolve_scenario
 
-        sc = resolve_scenario(
-            scenario,
-            {
-                k: v
-                for k, v in {
-                    "cancel_redundant": cancel_redundant,
-                    "size_dependent": size_dependent,
-                    "speeds": speeds,
-                    "churn": churn,
-                    "churn_schedule": churn_schedule,
-                    "churn_pairs_per_worker": churn_pairs_per_worker,
-                    "replan": replan,
-                    "speculation": speculation,
-                    "scheduler": scheduler,
-                    "workers_per_job": workers_per_job,
-                    "job_plans": job_plans,
-                    "jobs_per_stream": jobs_per_stream,
-                    "dtype": dtype,
-                    "rep_chunk": rep_chunk,
-                    "devices": devices,
-                }.items()
-                if v is not _UNSET
-            },
-            where="plan_cluster",
-        )
-        dist = dist if dist is not None else sc.dist
-        if dist is None:
-            raise ValueError("plan_cluster needs dist (positionally or via scenario.dist)")
-        if backend == "jax":
-            sc.validate(n_workers=self.n_workers, backend="jax")
-            if sc.is_dynamic or sc.is_space:
-                from ..cluster.epoch_scan import frontier_job_times_dynamic
+            sc = resolve_scenario(
+                scenario,
+                {
+                    k: v
+                    for k, v in {
+                        "cancel_redundant": cancel_redundant,
+                        "size_dependent": size_dependent,
+                        "speeds": speeds,
+                        "churn": churn,
+                        "churn_schedule": churn_schedule,
+                        "churn_pairs_per_worker": churn_pairs_per_worker,
+                        "replan": replan,
+                        "speculation": speculation,
+                        "scheduler": scheduler,
+                        "workers_per_job": workers_per_job,
+                        "job_plans": job_plans,
+                        "jobs_per_stream": jobs_per_stream,
+                        "dtype": dtype,
+                        "rep_chunk": rep_chunk,
+                        "devices": devices,
+                    }.items()
+                    if v is not _UNSET
+                },
+                where="plan_cluster",
+            )
+            dist = dist if dist is not None else sc.dist
+            if dist is None:
+                raise ValueError("plan_cluster needs dist (positionally or via scenario.dist)")
+            if backend == "jax":
+                sc.validate(n_workers=self.n_workers, backend="jax")
+                if sc.is_dynamic or sc.is_space:
+                    from ..cluster.epoch_scan import frontier_job_times_dynamic
 
-                rows = frontier_job_times_dynamic(
-                    dist,
-                    self.n_workers,
-                    self.candidates,
-                    n_reps,
-                    seed=seed,
-                    scenario=sc,
-                )
-            else:
-                if sc.dtype != "float32" or sc.devices != 1:
-                    raise ValueError(
-                        "Scenario.dtype/devices apply to dynamic scenarios (the "
-                        "jax epoch scan); the static frontier path supports "
-                        "rep_chunk only"
+                    rows = frontier_job_times_dynamic(
+                        dist,
+                        self.n_workers,
+                        self.candidates,
+                        n_reps,
+                        seed=seed,
+                        scenario=sc,
                     )
-                from ..cluster.vectorized import frontier_job_times
+                else:
+                    if sc.dtype != "float32" or sc.devices != 1:
+                        raise ValueError(
+                            "Scenario.dtype/devices apply to dynamic scenarios (the "
+                            "jax epoch scan); the static frontier path supports "
+                            "rep_chunk only"
+                        )
+                    from ..cluster.vectorized import frontier_job_times
 
-                rows = frontier_job_times(
-                    dist,
-                    self.n_workers,
-                    self.candidates,
-                    n_reps,
-                    seed=seed,
-                    size_dependent=sc.size_dependent,
-                    rep_chunk=sc.rep_chunk,
-                )
-        elif backend == "python":
-            from ..cluster.master import sample_job_times
+                    rows = frontier_job_times(
+                        dist,
+                        self.n_workers,
+                        self.candidates,
+                        n_reps,
+                        seed=seed,
+                        size_dependent=sc.size_dependent,
+                        rep_chunk=sc.rep_chunk,
+                    )
+            elif backend == "python":
+                from ..cluster.master import sample_job_times
 
-            sc.validate(n_workers=self.n_workers, backend="python")
-            rows = [
-                sample_job_times(
-                    dist,
-                    self.n_workers,
-                    b,
-                    n_reps,
-                    seed=seed + i,
-                    scenario=sc,
-                )
-                for i, b in enumerate(self.candidates)
-            ]
-        else:
-            raise ValueError(f"unknown backend {backend!r} (expected 'jax' or 'python')")
-        means, covs = _frontier_stats(rows)
-        b = self._select(means, covs, objective, blend)
-        return self._mk_plan(b, means, covs, objective, f"cluster_engine:{backend}")
+                sc.validate(n_workers=self.n_workers, backend="python")
+                rows = [
+                    sample_job_times(
+                        dist,
+                        self.n_workers,
+                        b,
+                        n_reps,
+                        seed=seed + i,
+                        scenario=sc,
+                    )
+                    for i, b in enumerate(self.candidates)
+                ]
+            else:
+                raise ValueError(f"unknown backend {backend!r} (expected 'jax' or 'python')")
+            means, covs = _frontier_stats(rows)
+            b = self._select(means, covs, objective, blend)
+            return self._mk_plan(b, means, covs, objective, f"cluster_engine:{backend}")
 
     # -- tail-SLO path (cheapest candidate meeting a response target) --------
 
@@ -483,84 +485,85 @@ class RedundancyPlanner:
             >>> plan.best.scheduler
             'fifo_gang'
         """
-        from ..cluster.scenario import SLO, Scenario
-        from .traces import TraceJob, poisson_stream
+        with span("entry.plan_slo"):
+            from ..cluster.scenario import SLO, Scenario
+            from .traces import TraceJob, poisson_stream
 
-        # default to whole-job service draws: under the §VI size model
-        # (size_dependent=True) a job's work scales with its source trace's
-        # task count, which is meaningful for real TraceJobs but arbitrary
-        # for ServiceTime workloads sampled into 4000-task stand-ins -- pass
-        # an explicit scenario to opt in
-        sc = scenario if scenario is not None else Scenario(size_dependent=False)
-        if slo is None:
-            slo = sc.slo
-        if slo is None:
-            raise ValueError("plan_slo needs an SLO (positionally or via scenario.slo)")
-        slos = tuple(slo) if isinstance(slo, (list, tuple)) else (slo,)
-        for s in slos:
-            if not isinstance(s, SLO):
-                raise ValueError(f"plan_slo: expected SLO entries, got {type(s)}")
-        rates = {float(s.arrival_rate) for s in slos}
-        if len(rates) != 1:
-            raise ValueError(
-                f"plan_slo: every SLO must share one arrival_rate, got {sorted(rates)}"
-            )
-        if isinstance(workload, (TraceJob, ServiceTime)):
-            workload = [workload]
-        sources = []
-        for i, w in enumerate(workload):
-            if isinstance(w, TraceJob):
-                sources.append(w)
-            elif isinstance(w, ServiceTime):
-                rng = np.random.default_rng(
-                    np.random.SeedSequence((int(seed), 0x51_0, i))
+            # default to whole-job service draws: under the §VI size model
+            # (size_dependent=True) a job's work scales with its source trace's
+            # task count, which is meaningful for real TraceJobs but arbitrary
+            # for ServiceTime workloads sampled into 4000-task stand-ins -- pass
+            # an explicit scenario to opt in
+            sc = scenario if scenario is not None else Scenario(size_dependent=False)
+            if slo is None:
+                slo = sc.slo
+            if slo is None:
+                raise ValueError("plan_slo needs an SLO (positionally or via scenario.slo)")
+            slos = tuple(slo) if isinstance(slo, (list, tuple)) else (slo,)
+            for s in slos:
+                if not isinstance(s, SLO):
+                    raise ValueError(f"plan_slo: expected SLO entries, got {type(s)}")
+            rates = {float(s.arrival_rate) for s in slos}
+            if len(rates) != 1:
+                raise ValueError(
+                    f"plan_slo: every SLO must share one arrival_rate, got {sorted(rates)}"
                 )
-                name = type(w).__name__.lower()
-                if any(src.name == name for src in sources):
-                    name = f"{name}{i}"
-                sources.append(
-                    TraceJob(
-                        name=name,
-                        family="fitted",
-                        task_times=w.sample_np(rng, (4000,)),
+            if isinstance(workload, (TraceJob, ServiceTime)):
+                workload = [workload]
+            sources = []
+            for i, w in enumerate(workload):
+                if isinstance(w, TraceJob):
+                    sources.append(w)
+                elif isinstance(w, ServiceTime):
+                    rng = np.random.default_rng(
+                        np.random.SeedSequence((int(seed), 0x51_0, i))
                     )
+                    name = type(w).__name__.lower()
+                    if any(src.name == name for src in sources):
+                        name = f"{name}{i}"
+                    sources.append(
+                        TraceJob(
+                            name=name,
+                            family="fitted",
+                            task_times=w.sample_np(rng, (4000,)),
+                        )
+                    )
+                else:
+                    raise ValueError(
+                        f"plan_slo: workload entries must be TraceJob or "
+                        f"ServiceTime, got {type(w)}"
+                    )
+            names = tuple(src.name for src in sources)
+            for s in slos:
+                if s.job_class is not None and s.job_class not in names:
+                    raise ValueError(
+                        f"plan_slo: SLO.job_class {s.job_class!r} is not a "
+                        f"workload class (classes: {names})"
+                    )
+            stream = poisson_stream(sources, rates.pop(), n_jobs, seed=seed)
+            if sc.is_dynamic:
+                evaluated = self._slo_epoch_candidates(
+                    workload, sc, slos, stream, n_reps, seed, schedulers
                 )
+                source = "epoch_scan"
             else:
-                raise ValueError(
-                    f"plan_slo: workload entries must be TraceJob or "
-                    f"ServiceTime, got {type(w)}"
+                evaluated = self._slo_stream_candidates(
+                    sc, slos, stream, n_reps, schedulers, pool_widths, slab
                 )
-        names = tuple(src.name for src in sources)
-        for s in slos:
-            if s.job_class is not None and s.job_class not in names:
-                raise ValueError(
-                    f"plan_slo: SLO.job_class {s.job_class!r} is not a "
-                    f"workload class (classes: {names})"
-                )
-        stream = poisson_stream(sources, rates.pop(), n_jobs, seed=seed)
-        if sc.is_dynamic:
-            evaluated = self._slo_epoch_candidates(
-                workload, sc, slos, stream, n_reps, seed, schedulers
+                source = "stream"
+            evaluated.sort(
+                key=lambda c: (not c.feasible, c.cost_worker_seconds, c.mean_response)
             )
-            source = "epoch_scan"
-        else:
-            evaluated = self._slo_stream_candidates(
-                sc, slos, stream, n_reps, schedulers, pool_widths, slab
+            best = evaluated[0] if evaluated and evaluated[0].feasible else None
+            return SLOPlan(
+                n_workers=self.n_workers,
+                slos=slos,
+                classes=names,
+                feasible=best is not None,
+                best=best,
+                candidates=tuple(evaluated),
+                source=source,
             )
-            source = "stream"
-        evaluated.sort(
-            key=lambda c: (not c.feasible, c.cost_worker_seconds, c.mean_response)
-        )
-        best = evaluated[0] if evaluated and evaluated[0].feasible else None
-        return SLOPlan(
-            n_workers=self.n_workers,
-            slos=slos,
-            classes=names,
-            feasible=best is not None,
-            best=best,
-            candidates=tuple(evaluated),
-            source=source,
-        )
 
     def _slo_grid(self, schedulers, pool_widths):
         """(scheduler, pool_width, B) triples for the plan_slo sweep."""
