@@ -84,7 +84,7 @@ from jax.scipy.special import gammaln
 
 from ..core.analysis import divisor_table, harmonic_tables
 from ..core.service_time import ServiceTime
-from ..spans import count, span
+from ..spans import count, readback, span
 from .scenario import UNSET, Scenario, Speculation, resolve_scenario
 from .scheduler import SCHEDULERS, JobPlan, is_space
 from .workers import ChurnProcess, ChurnSchedule
@@ -1459,10 +1459,7 @@ def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, se
         rest = tuple(jnp.asarray(x, t) for x, t in head + tail)
         count("h2d.bytes", sum(a.nbytes for a in rest))
     out = _get_runner(cfg)(tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, *rest)
-    with span("xfer.get"):
-        with span("wait.device"):
-            jax.block_until_ready(out)
-        res = {k: np.asarray(v)[:lanes] for k, v in out.items()}
+    res = {k: v[:lanes] for k, v in readback(out).items()}
     res["churn_horizon"] = horizon[:lanes]  # host-side, inf unless churn sampled
     return res
 

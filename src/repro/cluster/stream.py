@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.traces import TraceStream
-from ..spans import count, span
+from ..spans import count, readback, span
 from .scenario import Scenario
 from .vectorized import (
     STREAM_HIST_BINS,
@@ -106,14 +106,20 @@ class StreamStats:
     @classmethod
     def from_device(cls, acc: dict, classes: tuple | None = None) -> "StreamStats":
         """Pull a device accumulator dict back to host-side numpy arrays."""
-        with span("xfer.get"):
-            with span("wait.device"):
-                jax.block_until_ready(acc)
-            kw = {k: np.asarray(acc[k]) for k in _ACC_FIELDS}
-            if "class_hist" in acc:
-                kw.update({k: np.asarray(acc[k]) for k in _CLASS_FIELDS})
-                kw["classes"] = classes
-        return cls(**kw)
+        return cls.from_device_many([acc], classes)[0]
+
+    @classmethod
+    def from_device_many(cls, accs, classes: tuple | None = None) -> list:
+        """Pull several device accumulator dicts back in one batched readback.
+
+        One wait and one ``jax.device_get`` over every field of every
+        accumulator (:func:`repro.spans.readback`), so independent
+        :func:`simulate_stream` dispatches pay one device round trip, not one
+        per field.  ``classes`` names the per-class fields of each of them.
+        """
+        fields = _ACC_FIELDS + _CLASS_FIELDS
+        host = readback([{k: acc[k] for k in fields if k in acc} for acc in accs])
+        return [cls(**kw, classes=classes if "class_hist" in kw else None) for kw in host]
 
     @property
     def mean_response(self) -> np.ndarray:
@@ -435,91 +441,11 @@ def simulate_stream(
     changes a single drawn number.
     """
     with span("entry.simulate_stream"):
-        if not isinstance(stream, TraceStream):
-            raise TypeError(f"simulate_stream expects a TraceStream, got {type(stream)}")
-        sc = scenario if scenario is not None else Scenario(outputs="stream")
-        sc.validate(n_workers, backend="jax")
-        for field in ("churn", "churn_schedule", "speeds", "replan", "speculation", "job_plans"):
-            if getattr(sc, field) is not None:
-                raise ValueError(
-                    f"simulate_stream: Scenario.{field} is not supported on the "
-                    "streaming gang-pool path; use simulate_epochs for dynamic "
-                    "scenarios"
-                )
-        if sc.dtype == "float64":
-            import jax
-
-            if not jax.config.jax_enable_x64:
-                raise ValueError(
-                    "dtype='float64' needs jax x64 enabled "
-                    "(jax.config.update('jax_enable_x64', True))"
-                )
-        dt = jnp.float64 if sc.dtype == "float64" else jnp.float32
-        gangs, _pool, b, r = _resolve_pools(sc, n_workers, n_batches)
-        balanced = sc.scheduler_name == "balanced"
-        n_reps = int(n_reps)
-        n = stream.n_jobs
-        j_pad = n if slab is None else min(int(slab), n)
-        collect = sc.outputs == "full"
-
-        rngs = [stream.make_rng(rep) for rep in range(n_reps)]
-        # host-side f64 precompute, O(n): gaps and per-job batch-size scales
-        diffs = np.append(np.diff(stream.arrivals), 0.0)
-        scales_all = (
-            stream.n_tasks.astype(np.float64) / b
-            if sc.size_dependent
-            else np.ones(n, dtype=np.float64)
+        acc, classes, full_parts = _dispatch_stream(
+            stream, n_workers, n_batches, n_reps, scenario, slab
         )
-        edges = jnp.asarray(STREAM_HIST_EDGES, dtype=dt)
-        rel_free = jnp.full((n_reps, gangs), -float(stream.arrivals[0]), dtype=dt)
-        load = jnp.zeros((n_reps, gangs), dtype=dt)
-        classes = tuple(src.name for src in stream.sources)
-        n_classes = len(classes)
-        acc = stream_acc_init(n_reps, dt, n_classes)
-        full_parts: list = []
-        count("stream.job_reps", n_reps * n)
-        for lo, hi in stream.slabs(j_pad):
-            k = hi - lo
-            pad = (0, j_pad - k)
-            with span("draws.slab"):
-                draws = np.stack(
-                    [stream.sample_slab(rngs[s], lo, hi, b * r) for s in range(n_reps)]
-                ).reshape(n_reps, k, b, r)
-                if k < j_pad:  # final partial slab: pad with masked-out unit jobs
-                    draws = np.concatenate(
-                        [draws, np.ones((n_reps, j_pad - k, b, r))], axis=1
-                    )
-                scales = np.pad(scales_all[lo:hi], pad, constant_values=1.0)
-                gaps = np.pad(diffs[lo:hi], pad)
-                ids = np.pad(stream.job_ids[lo:hi], pad)
-                mask = np.arange(j_pad) < k
-            with span("xfer.put"):
-                inputs = (
-                    jnp.asarray(draws, dtype=dt),
-                    jnp.asarray(scales, dtype=dt),
-                    jnp.asarray(gaps, dtype=dt),
-                    jnp.asarray(mask),
-                    jnp.asarray(ids, dtype=jnp.int32),
-                )
-                count("h2d.bytes", sum(a.nbytes for a in inputs))
-            rel_free, load, acc, outs = _stream_slab(
-                *inputs,
-                rel_free,
-                load,
-                acc,
-                edges,
-                b=b,
-                r=r,
-                n_gangs=gangs,
-                cancel_redundant=bool(sc.cancel_redundant),
-                balanced=balanced,
-                collect=collect,
-                n_classes=n_classes,
-            )
-            if collect:
-                full_parts.append(tuple(np.asarray(o)[:, :k] for o in outs))
         stats = StreamStats.from_device(acc, classes=classes)
-        if not collect:
+        if full_parts is None:
             return stats
         waits, t_job, busy_j, planned_j, saved_j = (
             np.concatenate(parts, axis=1) for parts in zip(*full_parts)
@@ -533,3 +459,96 @@ def simulate_stream(
             saved_j=saved_j,
             stats=stats,
         )
+
+
+def _dispatch_stream(stream, n_workers, n_batches, n_reps, scenario, slab):
+    """:func:`simulate_stream` up to its last slab's dispatch, without waiting.
+
+    Returns the device accumulator, the class names and, for
+    ``outputs="full"``, the per-slab host outputs (else ``None``).  The
+    caller holds the ``entry.simulate_stream`` span around it and reads the
+    accumulator back with :meth:`StreamStats.from_device_many`, so a caller
+    with independent streams dispatches them all before it waits on any.
+    """
+    if not isinstance(stream, TraceStream):
+        raise TypeError(f"simulate_stream expects a TraceStream, got {type(stream)}")
+    sc = scenario if scenario is not None else Scenario(outputs="stream")
+    sc.validate(n_workers, backend="jax")
+    for field in ("churn", "churn_schedule", "speeds", "replan", "speculation", "job_plans"):
+        if getattr(sc, field) is not None:
+            raise ValueError(
+                f"simulate_stream: Scenario.{field} is not supported on the "
+                "streaming gang-pool path; use simulate_epochs for dynamic "
+                "scenarios"
+            )
+    if sc.dtype == "float64":
+        if not jax.config.jax_enable_x64:
+            raise ValueError(
+                "dtype='float64' needs jax x64 enabled "
+                "(jax.config.update('jax_enable_x64', True))"
+            )
+    dt = jnp.float64 if sc.dtype == "float64" else jnp.float32
+    gangs, _pool, b, r = _resolve_pools(sc, n_workers, n_batches)
+    balanced = sc.scheduler_name == "balanced"
+    n_reps = int(n_reps)
+    n = stream.n_jobs
+    j_pad = n if slab is None else min(int(slab), n)
+    collect = sc.outputs == "full"
+
+    rngs = [stream.make_rng(rep) for rep in range(n_reps)]
+    # host-side f64 precompute, O(n): gaps and per-job batch-size scales
+    diffs = np.append(np.diff(stream.arrivals), 0.0)
+    scales_all = (
+        stream.n_tasks.astype(np.float64) / b
+        if sc.size_dependent
+        else np.ones(n, dtype=np.float64)
+    )
+    edges = jnp.asarray(STREAM_HIST_EDGES, dtype=dt)
+    rel_free = jnp.full((n_reps, gangs), -float(stream.arrivals[0]), dtype=dt)
+    load = jnp.zeros((n_reps, gangs), dtype=dt)
+    classes = tuple(src.name for src in stream.sources)
+    n_classes = len(classes)
+    acc = stream_acc_init(n_reps, dt, n_classes)
+    full_parts = [] if collect else None
+    count("stream.job_reps", n_reps * n)
+    for lo, hi in stream.slabs(j_pad):
+        k = hi - lo
+        pad = (0, j_pad - k)
+        with span("draws.slab"):
+            draws = np.stack(
+                [stream.sample_slab(rngs[s], lo, hi, b * r) for s in range(n_reps)]
+            ).reshape(n_reps, k, b, r)
+            if k < j_pad:  # final partial slab: pad with masked-out unit jobs
+                draws = np.concatenate(
+                    [draws, np.ones((n_reps, j_pad - k, b, r))], axis=1
+                )
+            scales = np.pad(scales_all[lo:hi], pad, constant_values=1.0)
+            gaps = np.pad(diffs[lo:hi], pad)
+            ids = np.pad(stream.job_ids[lo:hi], pad)
+            mask = np.arange(j_pad) < k
+        with span("xfer.put"):
+            inputs = (
+                jnp.asarray(draws, dtype=dt),
+                jnp.asarray(scales, dtype=dt),
+                jnp.asarray(gaps, dtype=dt),
+                jnp.asarray(mask),
+                jnp.asarray(ids, dtype=jnp.int32),
+            )
+            count("h2d.bytes", sum(a.nbytes for a in inputs))
+        rel_free, load, acc, outs = _stream_slab(
+            *inputs,
+            rel_free,
+            load,
+            acc,
+            edges,
+            b=b,
+            r=r,
+            n_gangs=gangs,
+            cancel_redundant=bool(sc.cancel_redundant),
+            balanced=balanced,
+            collect=collect,
+            n_classes=n_classes,
+        )
+        if collect:
+            full_parts.append(tuple(np.asarray(o)[:, :k] for o in outs))
+    return acc, classes, full_parts

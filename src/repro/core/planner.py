@@ -591,18 +591,30 @@ class RedundancyPlanner:
     def _slo_stream_candidates(
         self, sc, slos, stream, n_reps, schedulers, pool_widths, slab
     ):
-        """Score the static grid on the streaming kernel's class histograms."""
-        from ..cluster.stream import simulate_stream
+        """Score the static grid on the streaming kernel's class histograms.
 
-        out = []
-        for sched, width, b in self._slo_grid(schedulers, pool_widths):
+        The candidates are independent, so every one is dispatched before any
+        is read back: the host builds candidate k+1 while the device scans
+        candidate k, and one batched readback ends the sweep.
+        """
+        from ..cluster.stream import StreamStats, _dispatch_stream
+
+        grid = self._slo_grid(schedulers, pool_widths)
+        accs, classes = [], None
+        for sched, width, b in grid:
             sc_c = sc.replace(
                 scheduler=sched, workers_per_job=width, outputs="stream",
                 n_batches=None, n_workers=None,
             )
-            stats = simulate_stream(
-                stream, self.n_workers, b, n_reps, scenario=sc_c, slab=slab
-            )
+            with span("entry.simulate_stream"):
+                acc, classes, _ = _dispatch_stream(
+                    stream, self.n_workers, b, n_reps, sc_c, slab
+                )
+            accs.append(acc)
+        out = []
+        for (sched, width, b), stats in zip(
+            grid, StreamStats.from_device_many(accs, classes)
+        ):
             achieved = tuple(
                 stats.quantile(s.quantile, job_class=s.job_class) for s in slos
             )

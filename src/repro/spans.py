@@ -12,7 +12,10 @@ so that an operator can read it back without a profiler.
   self time (the total less the time its child spans cover) and the number
   of entries.
 * :func:`count` adds to a counter of the same record (``h2d.bytes``: the
-  device bytes put by ``xfer.put`` spans; ``stream.job_reps``).
+  device bytes put by ``xfer.put`` spans; ``d2h.arrays``: the device arrays
+  :func:`readback` fetched; ``stream.job_reps``).
+* :func:`readback` is the package's one device-to-host path: one wait, then
+  one batched get of every array of a pytree.
 * :func:`last_call` returns the record of the last outermost call that
   ended on this thread.  An operator reads it after a plan or a replay to
   see where its host time went and how many bytes it put on the device.
@@ -28,9 +31,10 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
 from jax.profiler import TraceAnnotation
 
-__all__ = ["span", "count", "last_call"]
+__all__ = ["span", "count", "readback", "last_call"]
 
 _local = threading.local()
 
@@ -73,6 +77,22 @@ def count(name: str, n) -> None:
     if getattr(_local, "stack", None):
         counts = _local.rec["counts"]
         counts[name] = counts.get(name, 0) + n
+
+
+def readback(tree):
+    """``tree`` with every device array copied to a host numpy array, in one batched get.
+
+    One ``xfer.get`` span holds one ``wait.device`` span (the wait for the
+    device) and the copies, which ``jax.device_get`` starts together before it
+    converts any of them; ``d2h.arrays`` counts the arrays fetched.  A caller
+    with several independent device calls dispatches them all and reads them
+    back here in one pytree.
+    """
+    with span("xfer.get"):
+        with span("wait.device"):
+            jax.block_until_ready(tree)
+        count("d2h.arrays", len(jax.tree.leaves(tree)))
+        return jax.device_get(tree)
 
 
 def last_call() -> dict | None:

@@ -232,6 +232,40 @@ def test_plan_slo_feasible_survives_fresh_simulation():
     assert got <= slo.target_s * (1.0 + STREAM_QUANTILE_RTOL), (best, got)
 
 
+def test_plan_slo_static_grid_equals_each_candidate_alone():
+    """Dispatching the whole grid before one batched readback changes no value:
+    each candidate equals its own ``simulate_stream`` call, read back alone."""
+    workload = [Pareto(sigma=2.0, alpha=1.5), Exponential(mu=0.5)]
+    slos = (
+        SLO(quantile=0.99, target_s=40.0, arrival_rate=0.05),
+        SLO(quantile=0.9, target_s=12.0, arrival_rate=0.05, job_class="exponential"),
+    )
+    n_jobs, n_reps, seed, slab = 150, 2, 4, 64
+    plan = RedundancyPlanner(4).plan_slo(
+        workload, slos, n_jobs=n_jobs, n_reps=n_reps, seed=seed,
+        schedulers=("fifo_gang", "packed"), slab=slab,
+    )
+    sources = []
+    for i, w in enumerate(workload):  # the trace jobs plan_slo samples
+        rng = np.random.default_rng(np.random.SeedSequence((seed, 0x51_0, i)))
+        sources.append(TraceJob(type(w).__name__.lower(), "fitted", w.sample_np(rng, (4000,))))
+    stream = poisson_stream(sources, 0.05, n_jobs, seed=seed)
+    # B in 1, 2, 4 on the whole cluster, 1 on pools of 1, and 1, 2 on pools of 2
+    assert len(plan.candidates) == 6
+    for c in plan.candidates:
+        stats = simulate_stream(
+            stream, 4, c.n_batches, n_reps, slab=slab,
+            scenario=Scenario(size_dependent=False, scheduler=c.scheduler,
+                              workers_per_job=c.workers_per_job, outputs="stream"),
+        )
+        achieved = tuple(stats.quantile(s.quantile, job_class=s.job_class) for s in slos)
+        total = int(stats.count.sum())
+        assert c.achieved == achieved, c
+        assert c.cost_worker_seconds == float(stats.busy_sum.mean()), c
+        assert c.mean_response == float(stats.resp_sum.sum() / max(total, 1)), c
+        assert c.feasible == all(a <= s.target_s for a, s in zip(achieved, slos)), c
+
+
 def test_plan_slo_impossible_target_is_explicit():
     planner = RedundancyPlanner(4)
     plan = planner.plan_slo(

@@ -127,6 +127,7 @@ def test_simulate_stream_record():
     assert rec["counts"]["h2d.bytes"] == n_slabs * (reps * slab * b * r * 4 + slab * 13)
     total, own, _ = rec["spans"]["xfer.get"]
     assert own <= total and rec["spans"]["wait.device"][0] <= total
+    assert rec["counts"]["d2h.arrays"] == 12  # nine accumulator and three class fields
 
 
 def test_plan_slo_record_stays_within_its_span_budget():
@@ -135,12 +136,16 @@ def test_plan_slo_record_stays_within_its_span_budget():
         scenario=Scenario(size_dependent=False), n_jobs=120, n_reps=2, seed=3,
         schedulers=("fifo_gang", "packed"), slab=64,
     )
-    entries = _entries(spans.last_call())
+    rec = spans.last_call()
+    entries = _entries(rec)
     n_cand = len(plan.candidates)
     assert n_cand == 15
     assert entries["entry.plan_slo"] == 1 and entries["entry.simulate_stream"] == n_cand
     assert entries["draws.slab"] == 2 * n_cand
     assert sum(entries.values()) <= 150
+    # every candidate is dispatched first, then all are read back at once
+    assert entries["xfer.get"] == 1 and entries["wait.device"] == 1
+    assert rec["counts"]["d2h.arrays"] == 12 * n_cand
 
 
 @pytest.mark.filterwarnings("ignore:sampled churn horizon")
@@ -158,6 +163,9 @@ def test_plan_cluster_h2d_bytes_match_the_lane_shapes(width):
     rec = spans.last_call()
     assert _entries(rec) == {"entry.plan_cluster": 1, "entry.frontier_dynamic": 1,
                              "draws.lanes": 1, "xfer.put": 2, "xfer.get": 1, "wait.device": 1}
+    # the runner's outputs: starts, finishes, worker and cancelled seconds,
+    # failures, rescues and replans
+    assert rec["counts"]["d2h.arrays"] == 7
     n_pad, jobs_pad, ev_pad, resc_cap, _ = epoch_scan._shapes(n_workers, jobs, churn, None, pairs)
     lanes_pad = epoch_scan._pow2(len(cands) * math.ceil(reps / jobs))
     f32 = i32 = 4

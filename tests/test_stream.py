@@ -36,7 +36,8 @@ from repro.cluster import (
     simulate_epochs,
     simulate_stream,
 )
-from repro.cluster.stream import _ACC_FIELDS
+from repro import spans
+from repro.cluster.stream import _ACC_FIELDS, _CLASS_FIELDS, _dispatch_stream
 from repro.core.service_time import ShiftedExponential
 from repro.core.traces import (
     STREAM_VERSION,
@@ -201,6 +202,37 @@ def test_stream_f32_slab_invariant_and_sane():
     assert np.isfinite(s["mean_response"]) and s["mean_response"] > 0.0
     assert s["p50_response"] <= s["p95_response"] <= s["p99_response"]
     assert s["worker_seconds"] > 0.0
+
+
+@pytest.mark.parametrize("with_classes", [True, False], ids=["classes", "no_classes"])
+def test_from_device_many_equals_per_field_copies(with_classes):
+    """One batched readback of several accumulators gives each field as a copy of
+    its own would, dtype for dtype; ``from_device`` is its one-element case."""
+    st = _small_stream(40, seed=3)
+    sc = Scenario(outputs="stream", scheduler="packed", workers_per_job=4)
+    fields = _ACC_FIELDS + (_CLASS_FIELDS if with_classes else ())
+    accs = []
+    for b in (1, 2, 4):
+        acc, classes, _ = _dispatch_stream(st, 8, b, 2, sc, 16)
+        accs.append({k: acc[k] for k in fields})
+    want = [{k: np.asarray(acc[k]) for k in fields} for acc in accs]
+    got = StreamStats.from_device_many(accs, classes)
+    assert spans.last_call()["counts"] == {"d2h.arrays": len(fields) * len(accs)}
+    assert len(got) == len(accs)
+    for stats, host in zip(got, want):
+        for f in fields:
+            x = getattr(stats, f)
+            assert x.dtype == host[f].dtype, f
+            np.testing.assert_array_equal(x, host[f], err_msg=f)
+        if with_classes:
+            assert stats.classes == classes
+        else:
+            assert stats.class_hist is None and stats.classes is None
+    one = StreamStats.from_device(accs[0], classes)
+    for f in fields:
+        assert getattr(one, f).dtype == want[0][f].dtype, f
+        np.testing.assert_array_equal(getattr(one, f), want[0][f], err_msg=f)
+    assert one.classes == (classes if with_classes else None)
 
 
 def test_stream_rejects_dynamic_knobs_and_bad_pools():
