@@ -55,9 +55,10 @@ job-assignment and availability-timestamp vectors plus per-job plan tables
 replay concurrent jobs on disjoint worker subsets under heterogeneous
 (B, r, cancellation) plans -- ``packed`` / ``balanced`` / gang-mode
 ``fifo_gang`` placement, first-fit dispatch by earliest feasible time, and
-churn-aware rescue regrants.  ``scheduler`` / ``workers_per_job`` /
-``job_plans`` on the public entry points select it; the default
-configuration keeps the legacy single-gang lane untouched.
+churn-aware rescue regrants, and speculative backups as the engine launches
+them.  ``scheduler`` / ``workers_per_job`` / ``job_plans`` on the public
+entry points select it; the default configuration keeps the legacy
+single-gang lane untouched.
 
 Reproducibility contract: every lane (one Monte-Carlo rep of one candidate)
 derives its draws host-side from
@@ -309,10 +310,10 @@ class _RunnerCfg:
     # None selects the legacy single-gang lane; a policy name selects the
     # space-sharing lane (per-worker job assignment, per-job plan tables).
     scheduler: Optional[str] = None
-    # Reactive replication (gang lane only -- Scenario.validate rejects the
-    # space + speculation combination on this backend).  Enabling it switches
-    # the commit pass to event-granular groups so the trigger's median and
-    # candidate set evolve exactly as the engine's event loop interleaves them.
+    # Reactive replication, on either lane.  Enabling it switches the commit
+    # pass to completion groups no launch can fall between, so the trigger's
+    # median and candidate set evolve exactly as the engine's event loop
+    # interleaves them.
     spec: Optional[Speculation] = None
 
 
@@ -330,6 +331,38 @@ def _put(x, i, v, on):
     unfinished while 512 lanes and the CPU were right.
     """
     return jnp.where((jnp.arange(x.shape[0]) == i) & on, v, x)
+
+
+def _div_rn(a, b):
+    """``a / b`` in float32, rounded to nearest even as IEEE division is.
+
+    A TPU's float32 divide is not correctly rounded, so a quotient can land
+    one ulp off numpy's.  Here the quotient of the two 24-bit significands
+    comes from exact integer long division, 25 bits and a sticky remainder,
+    and is rounded once.  Zeros, infinities and NaNs take the plain divide;
+    a quotient in the subnormal range is not rounded as IEEE's (a TPU
+    flushes those to zero), and the lanes' times stay far from it.
+    """
+    ma, ea = jnp.frexp(a)
+    mb, eb = jnp.frexp(b)
+    ia = (jnp.abs(ma) * (1 << 24)).astype(jnp.int32)  # in [2**23, 2**24)
+    ib = (jnp.abs(mb) * (1 << 24)).astype(jnp.int32)
+    below = ia < ib  # a quotient under 1 takes one more bit
+    r = jnp.where(below, 2 * ia, ia)
+    q = r // ib  # the leading bit
+    r = r - q * ib
+    for _ in range(4):  # 24 more bits, 6 at a time (r * 64 < 2**30)
+        r = r * 64
+        d = r // ib
+        r = r - d * ib
+        q = q * 64 + d
+    keep, half = q >> 1, q & 1
+    up = (half == 1) & ((r != 0) | ((keep & 1) == 1))
+    mag = jnp.ldexp((keep + up.astype(jnp.int32)).astype(jnp.float32),
+                    ea - eb - below.astype(jnp.int32) - 23)
+    out = jnp.where(jnp.signbit(a) != jnp.signbit(b), -mag, mag)
+    plain = ~(jnp.isfinite(a) & jnp.isfinite(b) & (a != 0) & (b != 0))
+    return jnp.where(plain, a / b, out)
 
 
 def _build_lane(cfg: _RunnerCfg):
@@ -837,7 +870,7 @@ def _build_lane(cfg: _RunnerCfg):
             st, it = carry
             return (it < cfg.n_chunks) & ~done(st)
 
-        st, _ = jax.lax.while_loop(chunk_cond, chunk_body, (st, jnp.int32(0)))
+        st, n_chunks = jax.lax.while_loop(chunk_cond, chunk_body, (st, jnp.int32(0)))
         # flush replicas still in flight: their full duration is committed
         # worker time (it will burn whether or not we simulate it), which
         # keeps the invariant  ws(cancel on) + saved == ws(cancel off)
@@ -850,6 +883,9 @@ def _build_lane(cfg: _RunnerCfg):
             "n_worker_failures": st["n_fail"],
             "n_replicas_rescued": st["n_resc"],
             "n_replans": st["n_replans"],
+            # step chunks the lane ran: vmapped lanes step together, so the
+            # batch's largest is the runner's serial step count / _STEP_CHUNK
+            "n_chunks": n_chunks,
         }
         if spec is not None:
             out["n_speculative"] = st["n_spec"]
@@ -903,50 +939,131 @@ def _build_space_lane(cfg: _RunnerCfg):
     committed at the top of every step -- timestamps in ``w_avail`` make
     commit order irrelevant to placement decisions, unlike the legacy
     lane's projection from live replica state.
+
+    Speculation (``cfg.spec``) adds the engine's reactive backups: a bank of
+    completed-batch durations per active job for the running lower median,
+    a per-job backup budget, and a *launch* action on the heartbeat grid
+    that backs up the first lagging (job, batch) on the job's own free
+    worker, else a regranted unallocated one.  A backup runs in its
+    worker's own replica slot (a free worker holds no live replica), so
+    cancellation and churn treat it like any replica of its segment.  The
+    launch reads the state at its epoch, so with speculation on the step
+    takes its one action in time order -- a commit group (every completion
+    before the first epoch after the next one: no launch falls between
+    them), a rescue, a dispatch, a launch or a boundary -- each planned on
+    the step's input state.  Without speculation the lane traces as before.
     """
     n, jobs_pad, ev_pad = cfg.n, cfg.jobs_pad, cfg.ev_pad
     dt = jnp.dtype(cfg.dtype)
     widx = jnp.arange(n)
     J = jobs_pad  # sentinel: unallocated worker / free segment slot
     balanced = cfg.scheduler == "balanced"
+    spec = cfg.spec
+    # the observation bank: one entry per completed batch of an active job,
+    # tagged with its job (J = free).  Without churn the active jobs'
+    # batches fit on their disjoint allocations, so n entries suffice; a
+    # failed worker that rejoins can carry a second job's batches, so
+    # churned lanes get twice that
+    K = n if ev_pad == 1 else 2 * n
+    # Speculation's lanes index and reduce by segment, job and worker as
+    # dense masked reductions, not scatters and gathers: a TPU serializes the
+    # indices of a vmapped scatter (on a v5e, a scatter-min of 400 updates
+    # into 200 segments over 256 lanes takes ~680 us, the masked min ~26 us),
+    # and the speculative step runs thousands of times a plan.  Without
+    # speculation the lane keeps its scatter program.
+    dense = spec is not None
+    # A launch turns on exact comparisons (a lag against theta x the median, a
+    # grid point's floor), where one ulp decides whether a backup runs.  So
+    # speculation's float32 lanes divide correctly rounded, as numpy and the
+    # engine do, which a TPU's divide is not.
+    div = _div_rn if spec is not None and dt == jnp.float32 else jnp.divide
+
+    def onehot(idx, size):
+        return idx[None, :] == jnp.arange(size)[:, None]
+
+    def at_min(base, idx, vals):
+        if not dense:
+            return base.at[idx].min(vals)
+        return jnp.minimum(base, jnp.where(onehot(idx, base.shape[0]), vals, jnp.inf).min(axis=1))
+
+    def at_max(base, idx, vals):
+        if not dense:
+            return base.at[idx].max(vals)
+        return jnp.maximum(base, jnp.where(onehot(idx, base.shape[0]), vals, -jnp.inf).max(axis=1))
+
+    def at_add(base, idx, vals):
+        if not dense:
+            return base.at[idx].add(vals)
+        return base + jnp.where(onehot(idx, base.shape[0]), vals, 0).sum(axis=1).astype(base.dtype)
+
+    def at_set(base, idx, vals):
+        """``base.at[idx].set(vals)`` for indices that are distinct within ``base``
+        (repeats only at a trash slot the caller drops)."""
+        if not dense:
+            return base.at[idx].set(vals)
+        hit = onehot(idx, base.shape[0])
+        got = jnp.where(hit, vals, 0).sum(axis=1).astype(base.dtype)
+        return jnp.where(hit.any(axis=1), got, base)
+
+    def take(x, idx):
+        """``x[idx]`` for in-range indices."""
+        if not dense:
+            return x[idx]
+        hit = idx[:, None] == jnp.arange(x.shape[0])[None, :]
+        return jnp.where(hit, x[None, :], 0).sum(axis=1).astype(x.dtype)
 
     def lane(tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, b0, arrivals, speeds, n_real,
              jobs_real, n_tasks, req_tab, b_tab, cancel_tab, default_req):
-        del tau_spec  # speculation is gang-lane only (Scenario.validate)
         inf = jnp.asarray(jnp.inf, dt)
         jidx = jnp.arange(jobs_pad)
 
         def bscale(b):
-            return n_tasks / b.astype(dt) if cfg.size_dep else jnp.asarray(1.0, dt)
+            return div(n_tasks, b.astype(dt)) if cfg.size_dep else jnp.asarray(1.0, dt)
 
-        def step(st):
-            st = {**st}
-            e = st["e"]
-            t_next = ev_t[e]
-            rp_seg = jnp.concatenate([st["g_s"], widx])
-            rp_w = jnp.concatenate([widx, st["rb_w"]])
+        def commit(st, t_next, rp_seg, rp_w, gate=None, upto=None):
+            """Commit batch wins and replica retirements up to ``t_next`` (with
+            speculation: only under ``gate``, and only those before ``upto``)."""
             seg_of = jnp.clip(rp_seg, 0, n - 1)
             occupied = st["seg_job"] < J
-
-            # -- commit batch wins and replica retirements up to t_next
-            win = (
-                jnp.full(n + 1, jnp.inf, dt)
-                .at[rp_seg].min(jnp.where(st["rp_live"], st["rp_end"], jnp.inf))[:n]
-            )
+            win = at_min(
+                jnp.full(n + 1, jnp.inf, dt), rp_seg,
+                jnp.where(st["rp_live"], st["rp_end"], jnp.inf),
+            )[:n]
             newly = occupied & jnp.isfinite(win) & (win <= t_next)
-            on_win = st["rp_live"] & newly[seg_of] & (rp_seg < n)
-            win_r = win[seg_of]
+            if spec is not None:
+                newly = newly & gate & (win < upto)
+            on_win = st["rp_live"] & take(newly, seg_of) & (rp_seg < n)
+            win_r = take(win, seg_of)
+            if spec is not None:
+                # the batch's first completion is an observation for its job's
+                # median: the winner's duration, ties to the earliest-queued
+                # (smallest start) replica, as the engine's heap pops them
+                first = on_win & (st["rp_end"] == win_r)
+                w_st = at_min(jnp.full(n + 1, jnp.inf, dt), jnp.where(first, rp_seg, n),
+                              st["rp_start"])[:n]
+                free_b = st["obs_job"] == J
+                slot = at_set(jnp.full(K + 1, K, jnp.int32),
+                              jnp.where(free_b, jnp.cumsum(free_b) - 1, K),
+                              jnp.arange(K, dtype=jnp.int32))[:K]
+                pos = jnp.where(newly, take(slot, jnp.clip(jnp.cumsum(newly) - 1, 0, K - 1)), K)
+                st["obs_val"] = at_set(jnp.append(st["obs_val"], 0.0), pos, win - w_st)[:K]
+                st["obs_job"] = at_set(jnp.append(st["obs_job"], J), pos, st["seg_job"])[:K]
             # cancellation: every replica of a winning segment stops at the
             # win (the winner by construction, the losers reclaimed)
             kill_c = on_win & st["rp_cancel"]
             st["busy"] = st["busy"] + jnp.where(kill_c, win_r - st["rp_start"], 0.0).sum()
             st["saved"] = st["saved"] + jnp.where(kill_c, st["rp_end"] - win_r, 0.0).sum()
             # trash slot n takes the writes of replicas not killed
-            st["w_avail"] = (
-                jnp.append(st["w_avail"], 0.0).at[jnp.where(kill_c, rp_w, n)].set(win_r)[:n]
-            )
+            st["w_avail"] = at_set(
+                jnp.append(st["w_avail"], 0.0), jnp.where(kill_c, rp_w, n), win_r
+            )[:n]
             # non-cancel replicas retire individually at their own end
             retire = st["rp_live"] & ~st["rp_cancel"] & (st["rp_end"] <= t_next)
+            if spec is not None:
+                retire = retire & gate & (st["rp_end"] < upto)
+                last = jnp.maximum(jnp.max(jnp.where(newly, win, -inf)),
+                                   jnp.max(jnp.where(retire, st["rp_end"], -inf)))
+                st["t_now"] = jnp.maximum(st["t_now"], last)
             st["busy"] = st["busy"] + jnp.where(
                 retire, st["rp_end"] - st["rp_start"], 0.0
             ).sum()
@@ -954,14 +1071,14 @@ def _build_space_lane(cfg: _RunnerCfg):
             st["rp_live"] = live2
             # non-cancel survivors of a winning segment detach: the batch is
             # done but the straggler replica keeps burning to its end
-            gone = ~live2[:n] | (newly[jnp.clip(st["g_s"], 0, n - 1)] & (st["g_s"] < n))
+            gone = ~live2[:n] | (take(newly, jnp.clip(st["g_s"], 0, n - 1)) & (st["g_s"] < n))
             st["g_s"] = jnp.where(gone, n, st["g_s"])
 
             # -- job bookkeeping: wins decrement the owner's open count
             segj = st["seg_job"]
             i_new = jnp.where(newly, jnp.clip(segj, 0, J - 1), J)
-            st["job_left"] = jnp.append(st["job_left"], 0).at[i_new].add(-1)[:J]
-            st["job_fin"] = jnp.append(st["job_fin"], -jnp.inf).at[i_new].max(win)[:J]
+            st["job_left"] = at_add(jnp.append(st["job_left"], 0), i_new, -1)[:J]
+            st["job_fin"] = at_max(jnp.append(st["job_fin"], -jnp.inf), i_new, win)[:J]
             st["seg_job"] = jnp.where(newly, J, segj)  # freed at the win
             st["resc_pending"] = st["resc_pending"] & ~newly
             comp = st["dispatched"] & (st["job_left"] == 0) & ~st["recorded"]
@@ -969,15 +1086,36 @@ def _build_space_lane(cfg: _RunnerCfg):
             st["recorded"] = st["recorded"] | comp
             st["n_done"] = st["n_done"] + comp.sum(dtype=jnp.int32)
             wj = jnp.clip(st["w_job"], 0, J - 1)
-            rel = (st["w_job"] < J) & comp[wj]
+            rel = (st["w_job"] < J) & take(comp, wj)
             st["w_avail"] = jnp.where(
-                rel, jnp.maximum(st["w_avail"], st["job_fin"][wj]), st["w_avail"]
+                rel, jnp.maximum(st["w_avail"], take(st["job_fin"], wj)), st["w_avail"]
             )
             st["w_job"] = jnp.where(rel, J, st["w_job"])
+            if spec is not None:  # a finished job's observations leave the bank
+                oj = st["obs_job"]
+                st["obs_job"] = jnp.where((oj < J) & comp[jnp.clip(oj, 0, J - 1)], J, oj)
+            return st
 
-            # -- rescue: earliest-serveable pending segment, oldest first on
-            # ties; eligible workers are the job's own free allocation plus
-            # free unallocated workers (regrant)
+        def tier_of(st, j):
+            # space policies take the job's own free workers before regranting
+            # an unallocated one; the gang engine has no allocations and just
+            # takes the policy-first free worker
+            if cfg.scheduler == "fifo_gang":
+                return jnp.zeros(n, jnp.int32)
+            return jnp.where(st["w_job"] == j, 0, 1)
+
+        def pick(st, cand, tier):
+            """The policy's first worker of ``cand`` in the best tier: lowest wid
+            (packed, fifo_gang) or least load, ties by wid (balanced)."""
+            key2 = st["w_load"] if balanced else widx.astype(dt)
+            mt = cand & (tier == jnp.min(jnp.where(cand, tier, 2)))
+            mk = mt & (key2 == jnp.min(jnp.where(mt, key2, jnp.inf)))
+            return jnp.argmin(jnp.where(mk, widx, n))
+
+        def rescue(st, t_next):
+            """The earliest-serveable pending rescue, oldest first on ties;
+            eligible workers are the job's own free allocation plus free
+            unallocated workers (regrant)."""
             pend = st["resc_pending"]
             segjob = jnp.clip(st["seg_job"], 0, J - 1)
             free_w = st["alive"] & (st["w_job"] == J)
@@ -990,47 +1128,43 @@ def _build_space_lane(cfg: _RunnerCfg):
             m1 = serve_t == serve_min
             r_min = jnp.min(jnp.where(m1, st["resc_t"], jnp.inf))
             s_star = jnp.argmin(jnp.where(m1 & (st["resc_t"] == r_min), widx, n))
-            can_r = pend.any() & jnp.isfinite(serve_min) & (serve_min <= t_next)
+            ok = pend.any() & jnp.isfinite(serve_min) & (serve_min <= t_next)
             j_star = segjob[s_star]
             cand = st["alive"] & (st["w_avail"] <= serve_min) & (
                 (st["w_job"] == j_star) | (st["w_job"] == J)
             )
-            # space policies serve rescues from the job's own free workers
-            # before regranting an unallocated one; the gang engine has no
-            # allocations and just takes the policy-first free worker
-            if cfg.scheduler == "fifo_gang":
-                tier = jnp.zeros(n, jnp.int32)
-            else:
-                tier = jnp.where(st["w_job"] == j_star, 0, 1)
-            key2 = st["w_load"] if balanced else widx.astype(dt)
-            mt = cand & (tier == jnp.min(jnp.where(cand, tier, 2)))
-            mk = mt & (key2 == jnp.min(jnp.where(mt, key2, jnp.inf)))
-            w_star = jnp.argmin(jnp.where(mk, widx, n))
+            w_star = pick(st, cand, tier_of(st, j_star))
             rk = jnp.clip(st["resc_k"], 0, cfg.resc_cap - 1)
-            dur_r = (
-                tau_resc[rk, s_star]
-                * bscale(jnp.maximum(st["job_b"][j_star], 1))
-                / speeds[w_star]
+            dur_r = div(
+                tau_resc[rk, s_star] * bscale(jnp.maximum(st["job_b"][j_star], 1)),
+                speeds[w_star],
             )
-            st["rb_w"] = _put(st["rb_w"], s_star, w_star.astype(jnp.int32), can_r)
-            st["rp_start"] = _put(st["rp_start"], n + s_star, serve_min, can_r)
-            st["rp_end"] = _put(st["rp_end"], n + s_star, serve_min + dur_r, can_r)
-            st["rp_live"] = _put(st["rp_live"], n + s_star, True, can_r)
-            st["rp_cancel"] = _put(st["rp_cancel"], n + s_star, cancel_tab[j_star], can_r)
-            st["resc_pending"] = _put(st["resc_pending"], s_star, False, can_r)
-            st["w_job"] = _put(st["w_job"], w_star, j_star.astype(jnp.int32), can_r)
-            st["w_avail"] = _put(st["w_avail"], w_star, serve_min + dur_r, can_r)
+            return {"ok": ok, "t": serve_min, "s": s_star, "j": j_star, "w": w_star,
+                    "dur": dur_r}
+
+        def place_rescue(st, r, on):
+            s_star, j_star, w_star, t, dur_r = r["s"], r["j"], r["w"], r["t"], r["dur"]
+            st["rb_w"] = _put(st["rb_w"], s_star, w_star.astype(jnp.int32), on)
+            st["rp_start"] = _put(st["rp_start"], n + s_star, t, on)
+            st["rp_end"] = _put(st["rp_end"], n + s_star, t + dur_r, on)
+            st["rp_live"] = _put(st["rp_live"], n + s_star, True, on)
+            st["rp_cancel"] = _put(st["rp_cancel"], n + s_star, cancel_tab[j_star], on)
+            st["resc_pending"] = _put(st["resc_pending"], s_star, False, on)
+            st["w_job"] = _put(st["w_job"], w_star, j_star.astype(jnp.int32), on)
+            st["w_avail"] = _put(st["w_avail"], w_star, t + dur_r, on)
             # speed-weighted load (duration / speed), same op order as the
             # engine's _assign so f64 lanes replay placement bit-for-bit
-            w_load_new = st["w_load"][w_star] + dur_r / speeds[w_star]
-            st["w_load"] = _put(st["w_load"], w_star, w_load_new, can_r)
-            st["n_resc"] = st["n_resc"] + can_r
-            st["resc_k"] = st["resc_k"] + can_r
+            w_load_new = st["w_load"][w_star] + div(dur_r, speeds[w_star])
+            st["w_load"] = _put(st["w_load"], w_star, w_load_new, on)
+            st["n_resc"] = st["n_resc"] + on
+            st["resc_k"] = st["resc_k"] + on
+            return st
 
-            # -- dispatch: first-fit over undispatched jobs -- earliest
-            # feasible time (req-th smallest availability among free
-            # unallocated workers, floored at the job's arrival and the
-            # epoch start), ties broken by queue order
+        def dispatch(st, t_next, gate):
+            """First-fit over undispatched jobs -- earliest feasible time
+            (req-th smallest availability among free unallocated workers,
+            floored at the job's arrival and the epoch start), ties broken by
+            queue order.  ``gate(td)`` decides whether it may run."""
             n_alive = st["alive"].sum(dtype=jnp.int32)
             free_w2 = st["alive"] & (st["w_job"] == J)
             sa = jnp.sort(jnp.where(free_w2, st["w_avail"], jnp.inf))
@@ -1038,7 +1172,7 @@ def _build_space_lane(cfg: _RunnerCfg):
                 req_tab > 0, req_tab, jnp.where(default_req > 0, default_req, n_alive)
             )
             req_eff = jnp.clip(req, 1, jnp.maximum(n_alive, 1))
-            kth = sa[jnp.clip(req_eff - 1, 0, n - 1)]
+            kth = take(sa, jnp.clip(req_eff - 1, 0, n - 1))
             segfree = st["seg_job"] == J
             seg_rank = jnp.cumsum(segfree) - 1
             n_segfree = segfree.sum(dtype=jnp.int32)
@@ -1054,7 +1188,7 @@ def _build_space_lane(cfg: _RunnerCfg):
             )
             q_star = jnp.argmin(t_q)  # first min: lowest queue index
             td = t_q[q_star]
-            can_d = ~can_r & jnp.isfinite(td) & (td < t_next)
+            ok = gate(td)
             b_d = bq[q_star]
             r_d = req_eff[q_star] // b_d
             elig_d = free_w2 & (st["w_avail"] <= td)
@@ -1063,42 +1197,124 @@ def _build_space_lane(cfg: _RunnerCfg):
             sel_rep = elig_d & (rank < b_d * r_d)
             sel_alloc = elig_d & (rank < req_eff[q_star])
             # the beta-th dispatched batch takes the beta-th free segment
-            seg_by_beta = (
-                jnp.full(n + 1, n, jnp.int32)
-                .at[jnp.where(segfree, seg_rank, n)]
-                .set(widx.astype(jnp.int32))[:n]
-            )
-            w_seg = seg_by_beta[jnp.clip(rank % jnp.maximum(b_d, 1), 0, n - 1)]
+            seg_by_beta = at_set(
+                jnp.full(n + 1, n, jnp.int32), jnp.where(segfree, seg_rank, n),
+                widx.astype(jnp.int32),
+            )[:n]
+            w_seg = take(seg_by_beta, jnp.clip(rank % jnp.maximum(b_d, 1), 0, n - 1))
             # draw index = policy rank: the engine draws in placement order
-            dur = tau[q_star][jnp.clip(rank, 0, n - 1)] * bscale(b_d) / speeds
-            sel2 = jnp.concatenate([can_d & sel_rep, jnp.zeros(n, bool)])
-            st["g_s"] = jnp.where(can_d & sel_rep, w_seg, st["g_s"])
+            dur = div(take(tau[q_star], jnp.clip(rank, 0, n - 1)) * bscale(b_d), speeds)
+            return {"ok": ok, "t": td, "q": q_star, "b": b_d, "r": r_d, "rep": sel_rep,
+                    "alloc": sel_alloc, "seg": w_seg, "dur": dur, "segfree": segfree,
+                    "seg_rank": seg_rank}
+
+        def place_dispatch(st, d, on):
+            q_star, td, b_d, dur = d["q"], d["t"], d["b"], d["dur"]
+            sel_rep, sel_alloc = d["rep"], d["alloc"]
+            sel2 = jnp.concatenate([on & sel_rep, jnp.zeros(n, bool)])
+            st["g_s"] = jnp.where(on & sel_rep, d["seg"], st["g_s"])
             st["rp_live"] = st["rp_live"] | sel2
             st["rp_start"] = jnp.where(sel2, td, st["rp_start"])
             st["rp_end"] = jnp.where(
                 sel2, jnp.concatenate([td + dur, jnp.zeros(n, dt)]), st["rp_end"]
             )
             st["rp_cancel"] = jnp.where(sel2, cancel_tab[q_star], st["rp_cancel"])
-            st["w_job"] = jnp.where(can_d & sel_alloc, q_star.astype(jnp.int32), st["w_job"])
+            st["w_job"] = jnp.where(on & sel_alloc, q_star.astype(jnp.int32), st["w_job"])
             st["w_avail"] = jnp.where(
-                can_d & sel_rep,
+                on & sel_rep,
                 td + dur,
-                jnp.where(can_d & sel_alloc, td, st["w_avail"]),
+                jnp.where(on & sel_alloc, td, st["w_avail"]),
             )
-            st["w_load"] = st["w_load"] + jnp.where(can_d & sel_rep, dur / speeds, 0.0)
+            st["w_load"] = st["w_load"] + jnp.where(on & sel_rep, div(dur, speeds), 0.0)
             st["seg_job"] = jnp.where(
-                can_d & segfree & (seg_rank < b_d), q_star.astype(jnp.int32), st["seg_job"]
+                on & d["segfree"] & (d["seg_rank"] < b_d), q_star.astype(jnp.int32),
+                st["seg_job"],
             )
-            st["starts"] = _put(st["starts"], q_star, td, can_d)
-            st["dispatched"] = _put(st["dispatched"], q_star, True, can_d)
-            st["job_left"] = _put(st["job_left"], q_star, b_d, can_d)
-            st["job_b"] = _put(st["job_b"], q_star, b_d, can_d)
+            st["starts"] = _put(st["starts"], q_star, td, on)
+            st["dispatched"] = _put(st["dispatched"], q_star, True, on)
+            st["job_left"] = _put(st["job_left"], q_star, b_d, on)
+            st["job_b"] = _put(st["job_b"], q_star, b_d, on)
             if cfg.full_outputs:
-                br = (b_d << 16 | r_d).astype(jnp.int32)
-                st["br"] = _put(st["br"], q_star, br, can_d)
+                br = (b_d << 16 | d["r"]).astype(jnp.int32)
+                st["br"] = _put(st["br"], q_star, br, on)
+            return st
 
-            # -- otherwise apply one fail/join event (sim-over gated)
-            do_b = ~can_r & ~can_d
+        def hedge(st, rp_seg):
+            """The next speculative launch on the committed state, with the
+            engine's float expressions (``_next_spec_time``,
+            ``_on_spec_check``, ``_spec_pick_worker``): each job's lower median
+            of completed-batch durations, each unfinished batch's youngest live
+            replica crossing at start + theta x median, the launch on the first
+            heartbeat epoch strictly after both the crossing and now, and the
+            first lagging (job, batch) in order backed up there -- batches of
+            a job take segments in batch order.  Also the end of the next
+            commit group: no launch can fall before the first epoch after the
+            next completion, so every completion before it commits at once."""
+            # the grid spacing as an opaque value: a division by a constant
+            # compiles to a multiply by its reciprocal, which floors a grid
+            # point's own index one lower than the engine's true division
+            iv = jax.lax.optimization_barrier(jnp.asarray(spec.interval, dt))
+            theta = spec.theta
+            live = st["rp_live"]
+            t_evm = jnp.min(jnp.where(live, st["rp_end"], jnp.inf))
+            _, bval = jax.lax.sort((st["obs_job"], st["obs_val"]), num_keys=2)
+            cnt = at_add(jnp.zeros(J + 1, jnp.int32), st["obs_job"], 1)[:J]
+            med = take(bval, jnp.clip(jnp.cumsum(cnt) - cnt + (cnt - 1) // 2, 0, K - 1))
+            now = jnp.maximum(st["t_now"], st["t_epoch"])
+            free = st["alive"] & (st["w_avail"] <= now)
+            if cfg.scheduler == "fifo_gang":
+                room = free.any()
+            else:
+                own = at_add(jnp.zeros(J + 1, jnp.int32), jnp.where(free, st["w_job"], J), 1)
+                room = (own[:J] > 0) | (free & (st["w_job"] == J)).any()
+            job_ok = (
+                (cnt >= spec.min_observations) & (st["spec_used"] < spec.max_backups) & room
+            )
+            sj = jnp.clip(st["seg_job"], 0, J - 1)
+            m_s = take(med, sj)
+            y = at_max(jnp.full(n + 1, -jnp.inf, dt), rp_seg,
+                       jnp.where(live, st["rp_start"], -inf))[:n]
+            elig = (st["seg_job"] < J) & take(job_ok, sj) & jnp.isfinite(y)
+            k = jnp.maximum(jnp.floor(div(y + theta * m_s, iv)), jnp.floor(div(now, iv))) + 1.0
+            t_s = jnp.min(jnp.where(elig, k * iv, jnp.inf))
+            # fire re-check at the epoch itself, the engine's lagging(now - y,
+            # med); a check that launches nothing (the two forms can disagree
+            # by 1 ulp) still consumes the epoch, as the engine's does
+            lag = elig & ((t_s - y) > theta * m_s)
+            j_first = jnp.min(jnp.where(lag, st["seg_job"], J))
+            s_star = jnp.argmin(jnp.where(lag & (st["seg_job"] == j_first), widx, n))
+            j_star = jnp.clip(j_first, 0, J - 1)
+            cand = free & ((st["w_job"] == j_star) | (st["w_job"] == J))
+            w_s = pick(st, cand, tier_of(st, j_star))
+            sk = jnp.clip(st["spec_k"], 0, tau_spec.shape[0] - 1)
+            dur = div(tau_spec[sk, w_s] * bscale(jnp.maximum(st["job_b"][j_star], 1)),
+                      speeds[w_s])
+            return {"t": t_s, "launch": lag.any(), "s": s_star, "j": j_star, "w": w_s,
+                    "dur": dur, "t_evm": t_evm,
+                    "upto": (jnp.floor(div(t_evm, iv)) + 1.0) * iv}
+
+        def place_backup(st, h, on):
+            """A backup replica takes the free worker's own slot: a free worker
+            runs no live replica."""
+            s_star, j_star, w_s, t, dur = h["s"], h["j"], h["w"], h["t"], h["dur"]
+            go = on & h["launch"]
+            st["g_s"] = _put(st["g_s"], w_s, s_star.astype(jnp.int32), go)
+            st["rp_start"] = _put(st["rp_start"], w_s, t, go)
+            st["rp_end"] = _put(st["rp_end"], w_s, t + dur, go)
+            st["rp_live"] = _put(st["rp_live"], w_s, True, go)
+            st["rp_cancel"] = _put(st["rp_cancel"], w_s, cancel_tab[j_star], go)
+            st["w_job"] = _put(st["w_job"], w_s, j_star.astype(jnp.int32), go)
+            st["w_avail"] = _put(st["w_avail"], w_s, t + dur, go)
+            w_load_new = st["w_load"][w_s] + div(dur, speeds[w_s])
+            st["w_load"] = _put(st["w_load"], w_s, w_load_new, go)
+            st["spec_used"] = _put(st["spec_used"], j_star, st["spec_used"][j_star] + 1, go)
+            st["n_spec"] = st["n_spec"] + go
+            st["spec_k"] = st["spec_k"] + go
+            st["t_now"] = jnp.where(on, jnp.maximum(st["t_now"], t), st["t_now"])
+            return st
+
+        def boundary(st, e, rp_w, do_b):
+            """Apply one fail/join event (sim-over gated)."""
             sim_over = st["n_done"] >= jobs_real
             t_ev, w_raw, up = ev_t[e], ev_w[e], ev_up[e]
             act = do_b & (w_raw >= 0) & jnp.isfinite(t_ev) & ~sim_over
@@ -1112,7 +1328,7 @@ def _build_space_lane(cfg: _RunnerCfg):
             live3 = st["rp_live"] & ~kill
             st["rp_live"] = live3
             rp_seg3 = jnp.concatenate([st["g_s"], widx])
-            seg_cnt = jnp.zeros(n + 1, jnp.int32).at[rp_seg3].add(kill + 4096 * live3)[:n]
+            seg_cnt = at_add(jnp.zeros(n + 1, jnp.int32), rp_seg3, kill + 4096 * live3)[:n]
             lost = ((seg_cnt & 4095) > 0) & (seg_cnt < 4096) & (st["seg_job"] < J)
             st["resc_pending"] = st["resc_pending"] | lost
             st["resc_t"] = jnp.where(lost, t_ev, st["resc_t"])
@@ -1129,6 +1345,47 @@ def _build_space_lane(cfg: _RunnerCfg):
                 st["ep_times"] = _put(st["ep_times"], e, t_ev, do_fail | do_join)
             st["e"] = jnp.minimum(e + do_b, ev_pad - 1)
             return st
+
+        def step(st):
+            st = {**st}
+            e = st["e"]
+            t_next = ev_t[e]
+            rp_seg = jnp.concatenate([st["g_s"], widx])
+            rp_w = jnp.concatenate([widx, st["rb_w"]])
+            if spec is None:
+                # commit everything up to the boundary, then one action
+                st = commit(st, t_next, rp_seg, rp_w)
+                r = rescue(st, t_next)
+                can_r = r["ok"]
+                st = place_rescue(st, r, can_r)
+                d = dispatch(st, t_next, lambda td: ~can_r & jnp.isfinite(td) & (td < t_next))
+                can_d = d["ok"]
+                st = place_dispatch(st, d, can_d)
+                return boundary(st, e, rp_w, ~can_r & ~can_d)
+            # speculation reads the state at its epoch, so actions run in time
+            # order, one a step, from plans made on the step's input state:
+            # commit group, then rescue, dispatch, launch, boundary on ties
+            # (completions and the handlers they fire precede a check at the
+            # same instant; churn events precede a check)
+            r = rescue(st, t_next)
+            d = dispatch(st, t_next, lambda td: jnp.isfinite(td) & (td < t_next))
+            h = hedge(st, rp_seg)
+            t_c = jnp.where(h["t_evm"] <= t_next, h["t_evm"], jnp.inf)
+            t_r = jnp.where(r["ok"], r["t"], jnp.inf)
+            t_d = jnp.where(d["ok"], d["t"], jnp.inf)
+            t_s = jnp.where(h["t"] < t_next, h["t"], jnp.inf)
+            do_c = jnp.isfinite(t_c) & (t_c <= t_r) & (t_c <= t_d) & (t_c <= t_s)
+            do_r = ~do_c & r["ok"] & (t_r <= t_d) & (t_r <= t_s)
+            do_d = ~do_c & ~do_r & d["ok"] & (t_d <= t_s)
+            do_s = ~do_c & ~do_r & ~do_d & jnp.isfinite(t_s)
+            st = commit(st, t_next, rp_seg, rp_w, gate=do_c, upto=h["upto"])
+            st = place_rescue(st, r, do_r)
+            st = place_dispatch(st, d, do_d)
+            st = place_backup(st, h, do_s)
+            st["t_now"] = jnp.maximum(
+                st["t_now"], jnp.where(do_r, t_r, jnp.where(do_d, t_d, -inf))
+            )
+            return boundary(st, e, rp_w, ~(do_c | do_r | do_d | do_s))
 
         st = {
             "t_epoch": jnp.asarray(0.0, dt),
@@ -1163,6 +1420,15 @@ def _build_space_lane(cfg: _RunnerCfg):
         if cfg.full_outputs:
             st["br"] = jnp.zeros(jobs_pad, jnp.int32)
             st["ep_times"] = jnp.full(ev_pad, jnp.inf, dt)
+        if spec is not None:
+            st.update(
+                t_now=jnp.asarray(0.0, dt),
+                obs_val=jnp.zeros(K, dt),
+                obs_job=jnp.full(K, J, jnp.int32),
+                spec_used=jnp.zeros(jobs_pad, jnp.int32),
+                spec_k=jnp.int32(0),
+                n_spec=jnp.int32(0),
+            )
 
         def chunk_body(carry):
             s, it = carry
@@ -1173,7 +1439,7 @@ def _build_space_lane(cfg: _RunnerCfg):
             s, it = carry
             return (it < cfg.n_chunks) & (s["n_done"] < jobs_real)
 
-        st, _ = jax.lax.while_loop(chunk_cond, chunk_body, (st, jnp.int32(0)))
+        st, n_chunks = jax.lax.while_loop(chunk_cond, chunk_body, (st, jnp.int32(0)))
         flush = jnp.where(st["rp_live"], st["rp_end"] - st["rp_start"], 0.0).sum()
         out = {
             "starts": st["starts"],
@@ -1183,7 +1449,10 @@ def _build_space_lane(cfg: _RunnerCfg):
             "n_worker_failures": st["n_fail"],
             "n_replicas_rescued": st["n_resc"],
             "n_replans": jnp.int32(0),
+            "n_chunks": n_chunks,
         }
+        if spec is not None:
+            out["n_speculative"] = st["n_spec"]
         if cfg.full_outputs:
             out["br"] = st["br"]
             out["epoch_times"] = st["ep_times"]
@@ -1406,15 +1675,22 @@ def _shapes(n_workers, n_jobs, churn, churn_schedule, pairs, speculation=None):
     # allowance, plus one trailing commit; overruns leave jobs at inf exactly
     # like the engine's max_events cap
     if speculation is not None:
-        # event-granular commits consume one step per completion-time group
-        # (at most one per batch plus straggler/rescue retirements) plus one
-        # per backup launch and its (rare) 1-ulp re-arm
+        # commit groups each retire at least one replica (at most n_pad gang
+        # replicas a job, its backups and the rescues), and each job takes a
+        # dispatch and up to one launch and one (rare, 1-ulp) empty check per
+        # backup; rescues take a step each
         mb = speculation.max_backups
-        budget = jobs_pad * (n_pad + 1 + 2 * mb) + ev_pad + 2 * resc_cap + 2
+        budget = jobs_pad * (n_pad + 1 + 3 * mb) + ev_pad + 2 * resc_cap + 2
     else:
         budget = jobs_pad + ev_pad + resc_cap + 2
     n_chunks = -(-budget // _STEP_CHUNK)
     return n_pad, jobs_pad, ev_pad, resc_cap, n_chunks
+
+
+def _spec_cap(jobs_pad: int, spec: Optional[Speculation]) -> int:
+    """Rows of a lane's speculation draws: one row per possible launch
+    (``max_backups`` a job), drawn after the service and rescue draws."""
+    return jobs_pad * spec.max_backups if spec is not None else 0
 
 
 def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, seed,
@@ -1434,10 +1710,9 @@ def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, se
     idx = np.concatenate([lane_idx, np.arange(lanes_pad - lanes) + (1 << 30)])
     b0 = np.concatenate([b0, np.zeros(lanes_pad - lanes, np.int32)])
     dtype = jnp.dtype(cfg.dtype)
-    spec_cap = cfg.jobs_pad * cfg.spec.max_backups if cfg.spec is not None else 0
     tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, horizon = _prepare_lanes(
         dist, n_workers, cfg.n, idx, lanes, cfg.jobs_pad, cfg.ev_pad, cfg.resc_cap,
-        seed, churn, churn_schedule, pairs, dtype, spec_cap=spec_cap,
+        seed, churn, churn_schedule, pairs, dtype, spec_cap=_spec_cap(cfg.jobs_pad, cfg.spec),
     )
     # (host value, device dtype) of the runner's remaining inputs
     if cfg.scheduler is not None:
@@ -1458,8 +1733,11 @@ def _run_lanes(dist, cfg, n_workers, lane_idx, b0, arrivals_pad, n_jobs_real, se
     with span("xfer.put"):
         rest = tuple(jnp.asarray(x, t) for x, t in head + tail)
         count("h2d.bytes", sum(a.nbytes for a in rest))
-    out = _get_runner(cfg)(tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, *rest)
-    res = {k: v[:lanes] for k, v in readback(out).items()}
+    out = readback(_get_runner(cfg)(tau, tau_resc, tau_spec, ev_t, ev_w, ev_up, *rest))
+    count("scan.steps", int(out.pop("n_chunks").max()) * _STEP_CHUNK)
+    res = {k: v[:lanes] for k, v in out.items()}
+    if "n_speculative" in res:
+        count("spec.backups", int(res["n_speculative"].sum()))
     res["churn_horizon"] = horizon[:lanes]  # host-side, inf unless churn sampled
     return res
 
@@ -1663,18 +1941,19 @@ def simulate_epochs(
     (whole-cluster dispatch, per-job B and cancellation).  ``replan`` is
     mutually exclusive with space sharing.
 
-    ``speculation=Speculation(...)`` enables reactive backup replicas on the
-    gang lane: completed sibling-batch durations feed a running lower
+    ``speculation=Speculation(...)`` enables reactive backup replicas on
+    either lane: completed sibling-batch durations feed a running lower
     median, and a batch whose youngest live replica lags past ``theta x``
     that median earns one backup at the next heartbeat epoch (one launch per
     epoch, capped at ``max_backups`` per job) -- the exact trigger
     :class:`~repro.cluster.master.ClusterEngine` fires, computed with the
     same float expressions so the differential tests demand bit-equality on
-    shared schedules.  One live backup per batch: a batch whose backup is
-    still running is not re-eligible until it resolves (the engine's
-    youngest-replica rule differs only when the backup itself lags past the
-    trigger).  Mutually exclusive with ``replan`` and, on this backend, with
-    space sharing.
+    shared schedules.  Under space sharing the backup takes one of the
+    job's own free workers first, else regrants a free unallocated one.  On
+    the gang lane a batch whose backup is still running is not re-eligible
+    until it resolves (the engine's youngest-replica rule differs only when
+    the backup itself lags past the trigger); the space lane follows the
+    youngest-replica rule.  Mutually exclusive with ``replan``.
 
     Each Monte-Carlo rep derives every draw (replica durations, rescue draws,
     and -- when ``churn`` is given -- its own fail/join timeline of

@@ -568,17 +568,34 @@ class ClusterEngine:
         if math.isfinite(t):
             self.events.push(t, ev.SPEC_CHECK, seq=self._spec_seq)
 
+    def _superseded(self, kind: str, payload: dict) -> bool:
+        """Whether an event is stale: the end of a replica that was cancelled or
+        whose worker failed, a timer re-armed since it was pushed (the state
+        changed), a churn event scheduled before an earlier fail/join.  It
+        changes no state, so the loop skips it without re-arming the timer:
+        re-arming reads the clock, and a stale event at a live check's own
+        instant would push the check past it."""
+        if kind in (ev.BATCH_DONE, ev.TASK_FAIL):
+            w = self.pool[payload["wid"]]
+            return (not w.alive or w.epoch != payload["epoch"]
+                    or w.assignment != (payload["job_id"], payload["batch"]))
+        if kind == ev.SPEC_CHECK:
+            return not payload.get("scripted", False) and payload["seq"] != self._spec_seq
+        if kind in (ev.WORKER_FAIL, ev.WORKER_JOIN):
+            w = self.pool[payload["wid"]]
+            return w.alive == (kind == ev.WORKER_JOIN) or w.churn_epoch != payload["epoch"]
+        return False
+
     def _on_spec_check(self, seq: Optional[int] = None, scripted: bool = False) -> None:
         """Launch at most ONE backup: the first lagging (job, batch) in sorted
         order.  One launch per check keeps every substrate aligned -- the jax
         scan applies one action per event step, and the live trace stamps each
         launch separately -- and the re-arm (next recorded stamp) picks up any
         remaining laggard at the next heartbeat epoch, identically everywhere.
+        ``seq`` names the arming; the loop skips a superseded one before this.
         """
         cfg, pol = self.speculation, self._spec
         if not scripted:
-            if seq != self._spec_seq:
-                return  # stale timer: state changed since it was armed
             self._spec_armed_t = math.inf  # consumed; the loop re-arms
         now = self.clock.now
         for job_id in sorted(self.active):
@@ -620,8 +637,6 @@ class ClusterEngine:
 
     def _on_batch_done(self, job_id: int, batch: int, wid: int, epoch: int) -> None:
         worker = self.pool[wid]
-        if not worker.alive or worker.epoch != epoch or worker.assignment != (job_id, batch):
-            return  # stale: the replica was cancelled or the worker failed
         jexec = self.active.get(job_id)
         if jexec is None:
             # the job already completed (earliest cover); this replica ran to
@@ -694,8 +709,6 @@ class ClusterEngine:
         and either arm a backoff retry or -- budget exhausted with no sibling
         running or pending -- abandon the job (record finish = inf)."""
         worker = self.pool[wid]
-        if not worker.alive or worker.epoch != epoch or worker.assignment != (job_id, batch):
-            return  # stale: the replica was cancelled or the worker failed
         self._n_task_failures += 1
         self._release(worker)
         jexec = self.active.get(job_id)
@@ -769,8 +782,6 @@ class ClusterEngine:
 
     def _on_worker_fail(self, wid: int, epoch: int) -> None:
         worker = self.pool[wid]
-        if not worker.alive or worker.churn_epoch != epoch:
-            return  # stale failure (scheduled before an earlier fail/join)
         self._n_failures += 1
         self._epoch_times.append(self.clock.now)
         if worker.assignment is not None:
@@ -806,8 +817,6 @@ class ClusterEngine:
 
     def _on_worker_join(self, wid: int, epoch: int) -> None:
         worker = self.pool[wid]
-        if worker.alive or worker.churn_epoch != epoch:
-            return
         self._epoch_times.append(self.clock.now)
         worker.alive = True
         worker.epoch += 1
@@ -859,6 +868,8 @@ class ClusterEngine:
             t, kind, payload = self.events.pop()
             self.clock.advance(t)
             n_events += 1
+            if self._superseded(kind, payload):
+                continue  # it changes nothing, and the timer must not re-arm on it
             if kind == ev.JOB_ARRIVAL:
                 self.queue.append(payload["job"])
                 # rescues get first pick of free capacity even at arrivals

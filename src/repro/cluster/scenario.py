@@ -478,13 +478,6 @@ class Scenario:
                     "replanning are mutually exclusive adaptive policies -- "
                     "pass one of speculation / replan (controller)"
                 )
-            if backend == "jax" and self.is_space:
-                raise ValueError(
-                    "Scenario.speculation: speculative backups under "
-                    "space-sharing schedulers / per-job plans run on "
-                    "backend='python' only (the jax lane implements the gang "
-                    "regime)"
-                )
         if self.retry is not None:
             if not isinstance(self.retry, Retry):
                 raise ValueError(f"Scenario.retry: expected a Retry, got {type(self.retry)}")

@@ -506,6 +506,45 @@ def test_gated_write_sets_only_when_on_and_in_range():
     assert got.tolist() == [[0.0, 7.0, 2.0, 3.0], x.tolist(), x.tolist()]
 
 
+@pytest.mark.parametrize(
+    "law", ["hedged_durations", "wide_range", "integers", "grid", "near_ties", "specials"]
+)
+def test_div_rn_is_numpys_float32_division(law):
+    """The speculative space lane's float32 divide equals numpy's correctly rounded one
+    bit for bit: service draws over speeds, quotients across 18 decades, exact integer
+    quotients, the heartbeat grid's index, quotients a hair off a rounding tie, and
+    zeros, infinities, NaNs, overflow and underflow."""
+    import jax
+
+    from repro.cluster.epoch_scan import _div_rn
+
+    rng = np.random.default_rng(7)
+    m = 200_000
+    if law == "hedged_durations":
+        a = (rng.pareto(1.8, m) + 1) * rng.choice([1, 2, 4, 5, 10, 20, 25, 50, 100], m)
+        b = rng.uniform(0.5, 2.0, m)
+    elif law == "wide_range":
+        a, b = 10.0 ** rng.uniform(-9, 9, m), 10.0 ** rng.uniform(-9, 9, m)
+    elif law == "integers":
+        b = rng.integers(1, 1 << 12, m).astype(float)
+        a = b * rng.integers(1, 1 << 12, m)
+    elif law == "grid":
+        a, b = rng.integers(0, 1 << 20, m) * 0.25 + rng.choice([0.0, 0.125], m), 0.25
+    elif law == "near_ties":  # b x q for q a midpoint between two floats, nudged one ulp
+        q = rng.uniform(1.0, 2.0, m).astype(np.float32).astype(float) + 2.0**-24
+        b = rng.uniform(0.5, 2.0, m).astype(np.float32)
+        a = np.nextafter((q * b).astype(np.float32), rng.choice([-np.inf, np.inf], m))
+    else:  # 3e30 / 1e-30 overflows and 1e-30 / 3e30 underflows past the subnormals
+        v = [0.0, -0.0, 1.0, -2.5, np.inf, -np.inf, np.nan, 3e30, 1e-30]
+        a, b = (x.ravel() for x in np.meshgrid(v, v))
+    a = np.asarray(a, np.float32) * (1 if law == "specials" else rng.choice([-1, 1], m))
+    a = a.astype(np.float32)
+    b = np.broadcast_to(np.asarray(b, np.float32), a.shape)
+    with np.errstate(all="ignore"):
+        want = a / b
+    np.testing.assert_array_equal(np.asarray(jax.jit(_div_rn)(a, b)), want)
+
+
 def test_sharded_devices_match_single_device():
     """devices > 1 shards the lane grid via shard_map; per-lane seed
     derivation keeps the results exactly equal to single-device runs."""

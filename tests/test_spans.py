@@ -164,8 +164,13 @@ def test_plan_cluster_h2d_bytes_match_the_lane_shapes(width):
     assert _entries(rec) == {"entry.plan_cluster": 1, "entry.frontier_dynamic": 1,
                              "draws.lanes": 1, "xfer.put": 2, "xfer.get": 1, "wait.device": 1}
     # the runner's outputs: starts, finishes, worker and cancelled seconds,
-    # failures, rescues and replans
-    assert rec["counts"]["d2h.arrays"] == 7
+    # failures, rescues, replans and the lanes' step chunks
+    assert rec["counts"]["d2h.arrays"] == 8
+    # the serial steps: whole chunks, within the runner's budget
+    steps = rec["counts"]["scan.steps"]
+    assert steps > 0 and steps % epoch_scan._STEP_CHUNK == 0
+    assert steps <= epoch_scan._shapes(n_workers, jobs, churn, None, pairs)[4] * epoch_scan._STEP_CHUNK
+    assert "spec.backups" not in rec["counts"]  # no speculation, no count
     n_pad, jobs_pad, ev_pad, resc_cap, _ = epoch_scan._shapes(n_workers, jobs, churn, None, pairs)
     lanes_pad = epoch_scan._pow2(len(cands) * math.ceil(reps / jobs))
     f32 = i32 = 4
@@ -181,6 +186,52 @@ def test_plan_cluster_h2d_bytes_match_the_lane_shapes(width):
         n_div = epoch_scan._pow2(divisor_table(n_workers).shape[1])
         tail = f32 + (n_pad + 1) * n_div * i32 + 2 * (n_pad + 1) * f32
     assert rec["counts"]["h2d.bytes"] == lanes + shared + tail
+
+
+@pytest.mark.parametrize("policy", ["fifo_gang", "packed"])
+def test_plan_cluster_counts_backups_and_scan_steps(policy, monkeypatch):
+    """``spec.backups`` sums the lanes' launches and ``scan.steps`` is the largest lane's
+    chunk count times the chunk, both from the one readback of a lane batch."""
+    from repro.cluster import Speculation
+
+    seen = []
+    run = epoch_scan._run_lanes
+
+    def keep(*args, **kw):
+        out = run(*args, **kw)
+        seen.append(out)
+        return out
+
+    monkeypatch.setattr(epoch_scan, "_run_lanes", keep)
+    got = []
+    build = epoch_scan._get_runner
+
+    def chunks(cfg):
+        runner = build(cfg)
+
+        def call(*a):
+            out = runner(*a)
+            got.append(int(np.max(np.asarray(out["n_chunks"]))))
+            return out
+
+        return call
+
+    monkeypatch.setattr(epoch_scan, "_get_runner", chunks)
+    speeds = tuple(np.random.default_rng(0).uniform(0.5, 2.0, 8))
+    sc = Scenario(speeds=speeds, jobs_per_stream=8, cancel_redundant=True,
+                  speculation=Speculation(interval=0.25, theta=1.5, min_observations=2,
+                                          max_backups=2),
+                  scheduler=policy, workers_per_job=4 if policy == "packed" else None,
+                  rep_chunk=2)
+    RedundancyPlanner(8, candidates=[2, 4]).plan_cluster(
+        Pareto(1.0, 1.8), n_reps=32, seed=2, scenario=sc, backend="jax")
+    rec = spans.last_call()
+    assert len(seen) == len(got) == 2  # two lane batches of two streams a candidate
+    backups = sum(int(out["n_speculative"].sum()) for out in seen)
+    assert backups > 0 and rec["counts"]["spec.backups"] == backups
+    assert rec["counts"]["scan.steps"] == sum(got) * epoch_scan._STEP_CHUNK
+    # per batch: the eight outputs and the lanes' launches, in one readback
+    assert _entries(rec)["xfer.get"] == 2 and rec["counts"]["d2h.arrays"] == 2 * 9
 
 
 def _layer_module(name):

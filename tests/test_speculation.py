@@ -233,11 +233,15 @@ def test_scenario_rejects_speculation_with_replanning():
 
 
 def test_scenario_speculation_is_dynamic_and_python_only_for_space():
+    """Speculation is a dynamic knob; under space sharing both backends take it."""
     assert Scenario(speculation=SPEC).is_dynamic
     sc = Scenario(speculation=SPEC, workers_per_job=2)
-    sc.validate(n_workers=4, backend="python")  # fine on the engine
-    with pytest.raises(ValueError, match="backend='python' only"):
-        sc.validate(n_workers=4, backend="jax")
+    sc.validate(n_workers=4, backend="python")
+    sc.validate(n_workers=4, backend="jax")
+    for policy in ("packed", "balanced"):
+        Scenario(speculation=SPEC, scheduler=policy, workers_per_job=2).validate(
+            n_workers=4, backend="jax")
+    Scenario(speculation=SPEC, job_plans=[JobPlan()]).validate(n_workers=4, backend="jax")
 
 
 # --------------------------------------------------------------------------
@@ -388,3 +392,155 @@ def test_sample_job_times_speculation_kwarg_warns_scenario_does_not():
         scoped = sample_job_times(UNIT, 4, 4, 2, seed=0, scenario=sc)
     assert np.array_equal(loose, scoped)
     assert (scoped == 2.75).all()
+
+
+# --------------------------------------------------------------------------
+# the space lane: speculation under space sharing, engine vs jax, float64
+# --------------------------------------------------------------------------
+
+
+def _x64():
+    import jax
+
+    class _Ctx:
+        def __enter__(self):
+            self.prev = jax.config.jax_enable_x64
+            jax.config.update("jax_enable_x64", True)
+
+        def __exit__(self, *exc):
+            jax.config.update("jax_enable_x64", self.prev)
+
+    return _Ctx()
+
+
+def _assert_space_exact(er, vr):
+    """One float64 space-lane rep replays the engine's trajectory and accounting."""
+    e_start = np.array([r.start for r in er.records])
+    e_fin = np.array([r.finish for r in er.records])
+    assert np.allclose(vr.starts[0], e_start, rtol=1e-9, atol=1e-12)
+    assert np.allclose(vr.finishes[0], e_fin, rtol=1e-9, atol=1e-12)
+    assert np.isclose(vr.worker_seconds[0], er.worker_seconds, rtol=1e-9)
+    assert np.isclose(vr.cancelled_seconds_saved[0], er.cancelled_seconds_saved,
+                      rtol=1e-9, atol=1e-9)
+    assert int(vr.n_speculative[0]) == er.n_speculative
+    assert int(vr.n_worker_failures[0]) == er.n_worker_failures
+    assert int(vr.n_replicas_rescued[0]) == er.n_replicas_rescued
+
+
+def test_jax_space_lane_backs_up_on_its_own_allocation_first():
+    """The engine fixture above on the jax space lane: job 0's straggling batch is
+    backed up once on its own freed worker at 1.75 and covers at 2.75; job 1's subset
+    is untouched."""
+    from repro.cluster import simulate_epochs
+
+    speeds = (1.0, 0.25, 1.0, 1.0)
+    er = ClusterEngine(
+        4, seed=0, n_batches=2, cancel_redundant=True, speeds=speeds, speculation=SPEC,
+        scheduler="packed", workers_per_job=2,
+    ).run([Job(job_id=i, dist=UNIT, n_tasks=2) for i in range(2)])
+    sc = Scenario(speculation=SPEC, speeds=speeds, cancel_redundant=True, n_tasks=2,
+                  scheduler="packed", workers_per_job=2, dtype="float64")
+    with _x64():
+        vr = simulate_epochs(UNIT, 4, 2, np.zeros(2), 2, seed=0, scenario=sc)
+    assert (vr.n_speculative == 1).all()
+    assert (vr.compute_times == np.array([2.75, 1.0])).all()
+    _assert_space_exact(er, vr)
+
+
+# a shared timeline of three failures and three rejoins against six distinct
+# speeds (tests/test_space_sharing.py's), and a policy that fires often: every
+# combination below launches backups, and the churned ones rescue too
+SPACE_SCHED_TIMES = (0.7, 1.9, 3.35, 5.1, 7.77, 9.4)
+SPACE_SPEEDS = (1.0, 1.5, 0.7, 1.2, 0.9, 1.1)
+SPACE_SPEC = Speculation(interval=0.25, theta=1.2, min_observations=1, max_backups=2)
+
+
+@pytest.mark.parametrize("churn", [False, True], ids=["static", "churn"])
+@pytest.mark.parametrize("cancel", [False, True], ids=["cancel_off", "cancel_on"])
+@pytest.mark.parametrize("policy", ["packed", "balanced"])
+def test_jax_space_lane_speculation_matches_engine_exactly(policy, cancel, churn):
+    """Two 3-worker jobs side by side (B = 3, r = 1) on six workers: the space lane's
+    backups -- trigger, own-allocation-first placement, one launch an epoch, the
+    per-job budget, cancellation of the losers, and rescues of killed backups --
+    replay the engine exactly in float64."""
+    from repro.cluster import ChurnSchedule, simulate_epochs
+
+    d = Empirical(samples=(1.3,))
+    n, n_jobs, wpj = 6, 8, 3
+    sched = ChurnSchedule(times=SPACE_SCHED_TIMES, wids=(2, 5, 2, 0, 5, 0),
+                          ups=(False, False, True, False, True, True)) if churn else None
+    er = ClusterEngine(
+        n, seed=3, n_batches=3, cancel_redundant=cancel, speeds=SPACE_SPEEDS,
+        churn_schedule=sched, scheduler=policy, workers_per_job=wpj, speculation=SPACE_SPEC,
+    ).run([Job(job_id=i, dist=d, n_tasks=n) for i in range(n_jobs)])
+    sc = Scenario(cancel_redundant=cancel, speeds=SPACE_SPEEDS, churn_schedule=sched,
+                  scheduler=policy, workers_per_job=wpj, speculation=SPACE_SPEC,
+                  dtype="float64")
+    with _x64():
+        vr = simulate_epochs(d, n, 3, np.zeros(n_jobs), 1, seed=3, scenario=sc)
+    assert er.n_speculative > 0
+    assert (er.n_replicas_rescued > 0) == churn
+    starts = np.array([r.start for r in er.records])
+    fins = np.array([r.finish for r in er.records])
+    assert (starts[1:] < fins[:-1]).any()  # the jobs overlap: space sharing is exercised
+    _assert_space_exact(er, vr)
+
+
+def _generated_space_case(seed):
+    """A seeded scenario: policy, width, B, cancellation, churn, arrivals and policy
+    knobs drawn at random, with irregular speeds and times so that no two events tie."""
+    from repro.cluster import JobPlan as Plan
+
+    import strategies as scn
+
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(3, 9))
+    speeds = tuple(float(x) for x in rng.uniform(0.3, 2.0, size=n))
+    policy = ["packed", "balanced", "fifo_gang"][int(rng.integers(0, 3))]
+    wpj = int(rng.integers(1, n + 1))
+    b = int(rng.integers(1, wpj + 1))
+    cancel = bool(rng.integers(0, 2))
+    churn = bool(rng.integers(0, 2))
+    n_jobs = int(rng.integers(1, 7))
+    d = Empirical(samples=(float(rng.choice([1.0, 1.37, 2.13])),))
+    spec = Speculation(interval=float(rng.choice([0.23, 0.37, 0.11])),
+                       theta=float(rng.choice([1.1, 1.5, 2.0])),
+                       min_observations=int(rng.integers(1, 3)),
+                       max_backups=int(rng.integers(1, 3)))
+    arr = np.sort(rng.uniform(0, 3, size=n_jobs)) if rng.integers(0, 2) else np.zeros(n_jobs)
+    sched = scn.seeded_schedule(n, seed=seed, fail_rate=0.07, mean_downtime=1.2) if churn else None
+    space = dict(scheduler=policy, workers_per_job=wpj)
+    plan = None
+    if policy == "fifo_gang":  # per-job plans route the gang regime onto the space lane
+        plan, space = Plan(), dict(scheduler="fifo_gang")
+    return n, speeds, b, cancel, sched, n_jobs, d, spec, arr, plan, space
+
+
+# 125 and 220: grid points off the binary grid (the lane divides as the engine
+# does); 171 and 193: a superseded timer at a live check's own instant
+@pytest.mark.parametrize("seed", [7, 34, 125, 171, 193, 220])
+def test_jax_space_lane_speculation_generated_scenarios(seed):
+    from repro.cluster import simulate_epochs
+
+    n, speeds, b, cancel, sched, n_jobs, d, spec, arr, plan, space = _generated_space_case(seed)
+    jobs = [Job(job_id=i, dist=d, n_tasks=n, arrival=float(arr[i]), plan=plan)
+            for i in range(n_jobs)]
+    er = ClusterEngine(n, seed=seed, n_batches=b, cancel_redundant=cancel, speeds=speeds,
+                       churn_schedule=sched, speculation=spec, **space).run(jobs)
+    sc = Scenario(speculation=spec, speeds=speeds, cancel_redundant=cancel,
+                  churn_schedule=sched, dtype="float64",
+                  job_plans=[plan] if plan is not None else None, **space)
+    with _x64():
+        vr = simulate_epochs(d, n, b, arr, 1, seed=seed, scenario=sc)
+    _assert_space_exact(er, vr)
+
+
+def test_superseded_timer_does_not_move_a_live_check():
+    """A timer invalidated when its job finished can share its instant with the live
+    timer of the next job: the engine still checks at that instant (seed 171's
+    scenario launches its second backup at 10.35)."""
+    n, speeds, b, cancel, sched, n_jobs, d, spec, arr, plan, space = _generated_space_case(171)
+    rep = ClusterEngine(n, seed=171, n_batches=b, cancel_redundant=cancel, speeds=speeds,
+                        churn_schedule=sched, speculation=spec, **space).run(
+        [Job(job_id=i, dist=d, n_tasks=n, arrival=float(arr[i])) for i in range(n_jobs)])
+    assert rep.n_speculative == 2

@@ -145,3 +145,37 @@ def test_churned_epoch_scan_runner_compiles(one_chip, monkeypatch):
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         runner.lower(*_on(args, one_chip)).compile()
+
+
+def test_hedged_space_lane_runner_compiles(one_chip, monkeypatch):
+    """The space lane with speculative backups at the hedged fan-out's size: 200 packed
+    workers, 100-leaf requests, every B that divides 100, 2048 reps of 96 requests."""
+    from repro.cluster import Scenario, Speculation, epoch_scan
+    from repro.core.service_time import Pareto
+
+    seen = []
+    build = epoch_scan._get_runner
+
+    def shapes_only(cfg):
+        runner = build(cfg)
+
+        def call(*args):
+            seen.append((runner, args))
+            out = jax.eval_shape(runner, *args)
+            return jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), out)
+
+        return call
+
+    monkeypatch.setattr(epoch_scan, "_get_runner", shapes_only)
+    sc = Scenario(
+        speeds=tuple(np.random.default_rng(0).uniform(0.5, 2.0, 200)),
+        jobs_per_stream=96, n_tasks=100, cancel_redundant=True,
+        speculation=Speculation(theta=3.6, interval=0.25, max_backups=2, min_observations=5),
+        scheduler="packed", workers_per_job=100,
+    )
+    cands = [b for b in range(1, 101) if 100 % b == 0]
+    epoch_scan.frontier_job_times_dynamic(Pareto(1.0, 1.8), 200, cands, PLAN_REPS, scenario=sc)
+    ((runner, args),) = seen
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        runner.lower(*_on(args, one_chip)).compile()
