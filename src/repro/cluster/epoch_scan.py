@@ -951,7 +951,8 @@ def _build_space_lane(cfg: _RunnerCfg):
     takes its one action in time order -- a commit group (every completion
     before the first epoch after the next one: no launch falls between
     them), a rescue, a dispatch, a launch or a boundary -- each planned on
-    the step's input state.  Without speculation the lane traces as before.
+    the step's input state.  Without speculation the step commits every
+    completion up to the next churn boundary, then takes one action.
     """
     n, jobs_pad, ev_pad = cfg.n, cfg.jobs_pad, cfg.ev_pad
     dt = jnp.dtype(cfg.dtype)
@@ -965,13 +966,13 @@ def _build_space_lane(cfg: _RunnerCfg):
     # failed worker that rejoins can carry a second job's batches, so
     # churned lanes get twice that
     K = n if ev_pad == 1 else 2 * n
-    # Speculation's lanes index and reduce by segment, job and worker as
-    # dense masked reductions, not scatters and gathers: a TPU serializes the
-    # indices of a vmapped scatter (on a v5e, a scatter-min of 400 updates
-    # into 200 segments over 256 lanes takes ~680 us, the masked min ~26 us),
-    # and the speculative step runs thousands of times a plan.  Without
-    # speculation the lane keeps its scatter program.
-    dense = spec is not None
+    # The lane indexes and reduces by segment, job and worker as dense masked
+    # reductions, not scatters and gathers: a TPU serializes the indices of a
+    # vmapped scatter (on a v5e, a scatter-min of 400 updates into 200
+    # segments over 256 lanes takes ~680 us, the masked min ~26 us), and the
+    # step runs thousands of times a plan.  Each helper gives the scatter's
+    # answer bit for bit: min and max are exact, adds are on int32 counts, and
+    # a set or a gather adds its one hit to zeros.
     # A launch turns on exact comparisons (a lag against theta x the median, a
     # grid point's floor), where one ulp decides whether a backup runs.  So
     # speculation's float32 lanes divide correctly rounded, as numpy and the
@@ -982,33 +983,23 @@ def _build_space_lane(cfg: _RunnerCfg):
         return idx[None, :] == jnp.arange(size)[:, None]
 
     def at_min(base, idx, vals):
-        if not dense:
-            return base.at[idx].min(vals)
         return jnp.minimum(base, jnp.where(onehot(idx, base.shape[0]), vals, jnp.inf).min(axis=1))
 
     def at_max(base, idx, vals):
-        if not dense:
-            return base.at[idx].max(vals)
         return jnp.maximum(base, jnp.where(onehot(idx, base.shape[0]), vals, -jnp.inf).max(axis=1))
 
     def at_add(base, idx, vals):
-        if not dense:
-            return base.at[idx].add(vals)
         return base + jnp.where(onehot(idx, base.shape[0]), vals, 0).sum(axis=1).astype(base.dtype)
 
     def at_set(base, idx, vals):
         """``base.at[idx].set(vals)`` for indices that are distinct within ``base``
         (repeats only at a trash slot the caller drops)."""
-        if not dense:
-            return base.at[idx].set(vals)
         hit = onehot(idx, base.shape[0])
         got = jnp.where(hit, vals, 0).sum(axis=1).astype(base.dtype)
         return jnp.where(hit.any(axis=1), got, base)
 
     def take(x, idx):
         """``x[idx]`` for in-range indices."""
-        if not dense:
-            return x[idx]
         hit = idx[:, None] == jnp.arange(x.shape[0])[None, :]
         return jnp.where(hit, x[None, :], 0).sum(axis=1).astype(x.dtype)
 

@@ -506,6 +506,41 @@ def test_gated_write_sets_only_when_on_and_in_range():
     assert got.tolist() == [[0.0, 7.0, 2.0, 3.0], x.tolist(), x.tolist()]
 
 
+def test_space_lane_lowers_without_scatters(monkeypatch):
+    """The space lane indexes and reduces by segment, job and worker densely in every
+    configuration: a TPU serializes the indices of a vmapped scatter, so the
+    non-speculative packed runner of a churned plan lowers with no scatter op."""
+    import jax
+
+    from repro.cluster import Scenario, epoch_scan
+
+    seen = []
+    build = epoch_scan._get_runner
+
+    def shapes_only(cfg):
+        runner = build(cfg)
+
+        def call(*a):
+            seen.append((cfg, runner, a))
+            out = jax.eval_shape(runner, *a)
+            return jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), out)
+
+        return call
+
+    monkeypatch.setattr(epoch_scan, "_get_runner", shapes_only)
+    sc = Scenario(churn=ChurnProcess(fail_rate=0.02, mean_downtime=2.0),
+                  speeds=tuple(np.random.default_rng(0).uniform(0.5, 2.0, 8)),
+                  jobs_per_stream=8, churn_pairs_per_worker=4, cancel_redundant=True,
+                  scheduler="packed", workers_per_job=4)
+    frontier_job_times_dynamic(Pareto(1.0, 1.8), 8, [1, 2, 4], 16, scenario=sc)
+    ((cfg, runner, a),) = seen
+    assert cfg.scheduler == "packed" and cfg.spec is None and not cfg.stream
+    assert cfg.ev_pad > 1  # churned: the boundary's per-segment count runs too
+    text = runner.lower(*a).as_text()
+    assert "stablehlo.while" in text
+    assert "scatter" not in text
+
+
 @pytest.mark.parametrize(
     "law", ["hedged_durations", "wide_range", "integers", "grid", "near_ties", "specials"]
 )

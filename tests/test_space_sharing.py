@@ -30,9 +30,11 @@ except ModuleNotFoundError:  # test extra not installed: seeded fallback engine
 
 import strategies as scn
 from repro.cluster import (
+    ChurnProcess,
     ClusterEngine,
     Job,
     JobPlan,
+    Scenario,
     make_scheduler,
     sample_job_times,
     simulate_epochs,
@@ -41,7 +43,7 @@ from repro.cluster import (
 from repro.cluster.scheduler import BalancedScheduler, PackedScheduler
 from repro.cluster.workers import ChurnSchedule
 from repro.core.planner import RedundancyPlanner
-from repro.core.service_time import Empirical, Exponential
+from repro.core.service_time import Empirical, Exponential, Pareto
 
 # the crafted shared timeline every exact test replays: three failures, three
 # rejoins, distinct event times, against six distinct worker speeds
@@ -408,6 +410,26 @@ def test_rep_chunk_bit_identical_space_lane():
         assert np.array_equal(one.finishes, part.finishes)
         assert np.array_equal(one.starts, part.starts)
         assert np.array_equal(one.worker_seconds, part.worker_seconds)
+
+
+def test_rep_chunk_bit_identical_space_lane_wide_vmap():
+    """Bit-identity holds at 1024 vmapped lanes: a churned, packed, width-4 replay on
+    heterogeneous speeds, in one call against 8 reps a call."""
+    sc = Scenario(
+        scheduler="packed", workers_per_job=4, cancel_redundant=True,
+        speeds=tuple(np.random.default_rng(4).uniform(0.5, 2.0, 8)),
+        churn=ChurnProcess(fail_rate=0.05, mean_downtime=1.0), churn_pairs_per_worker=16,
+    )
+    args = (Pareto(1.0, 1.8), 8, 2, np.arange(6) * 0.5, 600)
+    one = simulate_epochs(*args, seed=11, scenario=sc)
+    part = simulate_epochs(*args, seed=11, scenario=sc.replace(rep_chunk=8))
+    assert one.finishes.shape == (600, 6)
+    assert np.isfinite(one.finishes).any() and one.n_worker_failures.sum() > 0
+    assert np.array_equal(one.finishes, part.finishes)
+    assert np.array_equal(one.starts, part.starts)
+    assert np.array_equal(one.worker_seconds, part.worker_seconds)
+    assert np.array_equal(one.cancelled_seconds_saved, part.cancelled_seconds_saved)
+    assert np.array_equal(one.n_replicas_rescued, part.n_replicas_rescued)
 
 
 # --------------------------------------------------------------------------
